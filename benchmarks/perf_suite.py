@@ -1254,44 +1254,10 @@ def bench_resilience(smoke: bool) -> None:
 
 
 # ---------------------------------------------------------------------------
-# mesh: cross-silo sharded fit + engine vs single device (subprocess
-# workers — XLA_FLAGS must force the device count before jax initializes)
+# mesh: cross-silo sharded fit + engine vs single device, in this process
+# on the devices it has (on CPU, force several with XLA_FLAGS=
+# --xla_force_host_platform_device_count=N before jax initializes)
 # ---------------------------------------------------------------------------
-
-
-def _mesh_env(devices: int) -> dict:
-    """Worker environment: strip any inherited device-count forcing, then
-    force ``devices`` host CPU devices (1 = a plain single-device run)."""
-    import os
-    env = dict(os.environ)
-    flags = [f for f in env.get("XLA_FLAGS", "").split()
-             if "xla_force_host_platform_device_count" not in f]
-    if devices > 1:
-        flags.append(f"--xla_force_host_platform_device_count={devices}")
-    env["XLA_FLAGS"] = " ".join(flags)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    env["PYTHONPATH"] = str(C.REPO_ROOT / "src")
-    return env
-
-
-def _run_mesh_worker(task: dict) -> dict:
-    """Run one measurement in a fresh interpreter (its own device count)
-    and parse the MESHRESULT line it prints."""
-    import subprocess
-    import sys
-    cmd = [sys.executable, "-m", "benchmarks.perf_suite",
-           "--mesh-worker", json.dumps(task)]
-    proc = subprocess.run(cmd, env=_mesh_env(task["devices"]),
-                          capture_output=True, text=True, timeout=3000,
-                          cwd=C.REPO_ROOT)
-    if proc.returncode != 0:
-        raise RuntimeError(f"mesh worker {task} failed:\n{proc.stdout}\n"
-                           f"{proc.stderr}")
-    for line in reversed(proc.stdout.splitlines()):
-        if line.startswith("MESHRESULT "):
-            return json.loads(line[len("MESHRESULT "):])
-    raise RuntimeError(f"mesh worker {task} printed no MESHRESULT:\n"
-                       f"{proc.stdout}\n{proc.stderr}")
 
 
 def _mesh_fit_slab(n_clients: int, queries: int, d_emb: int,
@@ -1316,10 +1282,10 @@ def _mesh_fit_slab(n_clients: int, queries: int, d_emb: int,
     }
 
 
-def _mesh_worker_fit(task: dict) -> dict:
-    """Measure one federated fit configuration in-process (the parent
-    forced our device count via XLA_FLAGS). Reports clients/s plus the
-    FIT_TRACE_LOG growth across the timed repeats (zero-retrace pin)."""
+def _mesh_fit(task: dict) -> dict:
+    """Measure one federated fit configuration on ``task["devices"]``
+    devices. Reports clients/s plus the FIT_TRACE_LOG growth across the
+    timed repeats (zero-retrace pin)."""
     import time
 
     import repro.sharding as shd
@@ -1362,11 +1328,11 @@ def _mesh_worker_fit(task: dict) -> dict:
             "retraces": len(F.FIT_TRACE_LOG) - n_trace}
 
 
-def _mesh_worker_engine(task: dict) -> dict:
-    """Measure engine decode tokens/s (the parent forced our device
-    count): a slot-saturating batch on a uniform pool, solo or with the
-    KV pool sharded slot-parallel over a "data" mesh. Token parity versus
-    the solo engine is pinned in tests/test_mesh.py; here we time."""
+def _mesh_engine(task: dict) -> dict:
+    """Measure engine decode tokens/s: a slot-saturating batch on a
+    uniform pool, solo or with the KV pool sharded slot-parallel over a
+    "data" mesh of ``task["devices"]``. Token parity versus the solo
+    engine is pinned in tests/test_mesh.py; here we time."""
     import time
 
     import repro.sharding as shd
@@ -1403,24 +1369,17 @@ def _mesh_worker_engine(task: dict) -> dict:
             "retraces": len(TRACE_LOG) - n_trace}
 
 
-def _mesh_worker_main(payload: str) -> None:
-    task = json.loads(payload)
-    out = (_mesh_worker_fit(task) if task["kind"] == "fit"
-           else _mesh_worker_engine(task))
-    print("MESHRESULT " + json.dumps(out))
-
-
 def bench_mesh(smoke: bool, profile: str | None = None) -> None:
-    """Cross-silo mesh execution vs single device, each measurement in its
-    own interpreter (forced host device count): the sharded federated fit
-    (PowerLaw client population, full and cohort-sampled rounds) and the
-    slot-parallel engine decode. On hosts with fewer cores than devices
-    the mesh path pays pure dispatch + collective overhead with no
-    parallel hardware to win it back — ``meta.host_cpus`` records that so
-    CI gates the throughput floor on real parallelism being present."""
+    """Cross-silo mesh execution vs single device, in this process over
+    every device it has: the sharded federated fit (PowerLaw client
+    population, full and cohort-sampled rounds) and the slot-parallel
+    engine decode. On hosts with fewer cores than forced CPU devices the
+    mesh path pays pure dispatch + collective overhead with no parallel
+    hardware to win it back — ``meta.host_cpus`` records that so CI gates
+    the throughput floor on real parallelism being present."""
     import os
 
-    devices = 8
+    devices = len(jax.devices())
     if smoke:
         fit_cases = [("fit_256c", dict(kind="fit", n_clients=256,
                                        queries=8, rounds=2, repeats=2))]
@@ -1437,11 +1396,12 @@ def bench_mesh(smoke: bool, profile: str | None = None) -> None:
 
     results = {}
     for name, case in fit_cases + [("engine", eng_case)]:
-        for dev in (1, devices):
+        for dev in sorted({1, devices}):
             task = {**case, "devices": dev}
             if profile and dev == devices and case["kind"] == "fit":
                 task["profile"] = os.path.join(profile, f"mesh_{name}")
-            results[(name, dev)] = _run_mesh_worker(task)
+            results[(name, dev)] = (_mesh_fit(task) if case["kind"] == "fit"
+                                    else _mesh_engine(task))
 
     for name, case in fit_cases:
         solo, mesh = results[(name, 1)], results[(name, devices)]
@@ -1450,7 +1410,7 @@ def bench_mesh(smoke: bool, profile: str | None = None) -> None:
                f"{solo['clients_per_s']:.0f} clients/s, single device")
         C.emit(f"mesh_{name}_{devices}dev", mesh["fit_s"] * 1e6,
                f"{mesh['clients_per_s']:.0f} clients/s, shard_map over "
-               f"{devices} forced host devices",
+               f"{devices} {jax.devices()[0].platform} devices",
                speedup_vs_baseline=solo["fit_s"] / mesh["fit_s"])
     solo, mesh = results[("engine", 1)], results[("engine", devices)]
     assert mesh["retraces"] == 0, "engine: mesh decode retraced"
@@ -1458,7 +1418,7 @@ def bench_mesh(smoke: bool, profile: str | None = None) -> None:
            f"{solo['tokens_per_s']:.0f} tokens/s, single device")
     C.emit(f"mesh_engine_{devices}dev", mesh["engine_s"] * 1e6,
            f"{mesh['tokens_per_s']:.0f} tokens/s, KV pool slot-parallel "
-           f"over {devices} forced host devices",
+           f"over {devices} {jax.devices()[0].platform} devices",
            speedup_vs_baseline=solo["engine_s"] / mesh["engine_s"])
     C.write_bench(_bench_file("mesh", smoke), meta={
         "smoke": smoke, "devices": devices,
@@ -1487,11 +1447,7 @@ def main() -> None:
     ap.add_argument("--profile", default=None, metavar="DIR",
                     help="capture a jax.profiler trace per section into "
                          "DIR (TensorBoard format); off by default")
-    ap.add_argument("--mesh-worker", default=None, help=argparse.SUPPRESS)
     args = ap.parse_args()
-    if args.mesh_worker is not None:
-        _mesh_worker_main(args.mesh_worker)
-        return
 
     sections = [s.strip() for s in args.sections.split(",") if s.strip()]
     unknown = set(sections) - set(SECTIONS)
@@ -1503,7 +1459,7 @@ def main() -> None:
 
     for s in sections:
         if s == "mesh":
-            # subprocess workers profile themselves (own device counts)
+            # traces each sharded fit on its own (one trace per case)
             bench_mesh(args.smoke, profile=args.profile)
             continue
         ctx = (jax.profiler.trace(os.path.join(args.profile, s))

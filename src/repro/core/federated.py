@@ -7,8 +7,9 @@ client's local epoch in parallel; on a multi-device mesh the same round is
 stacked slab, cohort slabs are exchanged with masked ``psum``s, and the
 updates return to the (replicated) server aggregation through a sorted
 ``all_gather`` — so every ``Aggregator`` strategy runs verbatim on the
-full global-order stack and the sharded fit is bit-for-bit the in-process
-one on a fixed key. ``fedavg(mesh=...)`` selects it.
+full global-order stack and the sharded fit matches the in-process one on
+a fixed key within ``MESH_PARAM_ATOL`` / ``MESH_LOSS_RTOL`` (the mesh
+parity contract below). ``fedavg(mesh=...)`` selects it.
 
 Client dataset layout (N clients, padded to D_max rows):
   {"x": (N, D, d_emb), "m": (N, D) int32, "acc": (N, D), "cost": (N, D),
@@ -35,6 +36,28 @@ from repro.train.optim import SGD, AdamW
 # keeps core/ from importing serve/, so the fit path gets its own log).
 # Tests pin that cohort-sampled fits never retrace across rounds/syncs.
 FIT_TRACE_LOG = collections.deque(maxlen=4096)
+
+#: Mesh parity contract: the mesh fit against the in-process fit on the
+#: same key and client stack. Sampling, the gathered update stack and the
+#: aggregation run the in-process code verbatim, but the compiler lowers
+#: the per-device batch of N / n_dev client updates, and the reduction of
+#: the per-round loss diagnostic, in an order of its own. So params agree
+#: within MESH_PARAM_ATOL (absolute) and the per-round loss within
+#: MESH_LOSS_RTOL (relative: a few float32 ulps). On the CPU backend the
+#: params come out bit-for-bit whenever each device trains >= 2 clients.
+MESH_PARAM_ATOL = 1e-5
+MESH_LOSS_RTOL = 1e-6
+
+
+def mesh_fit_gap(ref_params, ref_loss, params, loss) -> tuple[float, float]:
+    """(largest absolute param difference, largest relative per-round loss
+    difference) between two fits — what the mesh parity contract bounds."""
+    dp = max(float(np.max(np.abs(np.asarray(a) - np.asarray(b))))
+             for a, b in zip(jax.tree.leaves(ref_params),
+                             jax.tree.leaves(params)))
+    r, g = np.asarray(ref_loss, np.float64), np.asarray(loss, np.float64)
+    dl = float(np.max(np.abs(r - g) / np.maximum(np.abs(r), 1e-30)))
+    return dp, dl
 
 
 def reset_fit_trace_log() -> None:

@@ -8,12 +8,13 @@ models/attention.init_kv_cache). Both kernels follow the jnp reference
 path's dtype discipline (``_masked_grouped_attn``): q·k dots in the cache
 dtype, probs downcast to the value dtype before the p·v dot, f32
 accumulators only — so scores and attention weights quantize identically
-to the reference and greedy argmax tokens agree on bf16 caches. Grid (B, Hkv, nS); the innermost seq
-dimension accumulates (m, l, acc) in VMEM scratch. A validity bound masks
-unwritten cache slots (positions ≥ n_valid); it may be per-batch — a (B,)
-vector — so a continuous-batching slot pool (serve/engine.py) can decode
-requests sitting at different positions in one launch. A row whose bound
-is 0 (fully-invalid slot — e.g. a drained pool row) returns exactly 0.
+to the reference and greedy argmax tokens agree on bf16 caches. Grid
+(B, Hkv, nS); the innermost seq dimension accumulates (m, l, acc) in VMEM
+scratch. A validity bound masks unwritten cache slots (positions ≥
+n_valid); it is per-batch — a (B,) vector scalar-prefetched into SMEM —
+so a continuous-batching slot pool (serve/engine.py) can decode requests
+sitting at different positions in one launch. A row whose bound is 0
+(fully-invalid slot — e.g. a drained pool row) returns exactly 0.
 
 ``paged_decode_attention_pallas`` is the vLLM-style variant for the paged
 KV pool (serve/kv_cache.alloc_page_pool): the cache is a flat pool of
@@ -35,13 +36,24 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# jax 0.4.x names it TPUCompilerParams; 0.5+ renamed to CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+
+
+def _seq_block(S: int, block_s: int) -> int:
+    """Seq block for an S-position cache: S itself when it fits one block
+    (a block equal to the full dim is always a legal TPU tile), else the
+    largest multiple of 8 no larger than ``block_s`` that divides S."""
+    if S <= block_s:
+        return S
+    for bs in range(block_s - block_s % 8, 7, -8):
+        if S % bs == 0:
+            return bs
+    raise ValueError(f"cache length {S} > block_s={block_s} has no "
+                     "sublane-aligned (multiple of 8) block that divides it")
 
 
 def _kernel(nv_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
             bs: int, ns: int, scale: float):
+    b = pl.program_id(0)
     ik = pl.program_id(2)
 
     @pl.when(ik == 0)
@@ -63,7 +75,7 @@ def _kernel(nv_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     pos = ik * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    valid = pos < nv_ref[0, 0]
+    valid = pos < nv_ref[b]
     s = jnp.where(valid, s, NEG_INF)
     m_prev = m_s[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -85,43 +97,45 @@ def _kernel(nv_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
-def decode_attention_pallas(q, k_cache, v_cache, n_valid, *,
-                            block_s: int = 512, interpret: bool = True):
+def decode_attention_pallas(q, k_cache, v_cache, n_valid, *, interpret: bool,
+                            block_s: int = 512):
     """q: (B, Hkv, g, hd); caches: (B, Hkv, S, hd) head-major;
     n_valid: scalar int32 — number of filled cache slots — or a (B,)
     vector giving each batch row (pool slot) its own validity bound.
-    Returns (B, Hkv, g, hd)."""
+    S is any length up to ``block_s``, or a multiple of 8 beyond it (see
+    ``_seq_block``). The validity vector is scalar-prefetched into SMEM
+    (as in the paged kernel). Returns (B, Hkv, g, hd)."""
     B, Hkv, g, hd = q.shape
     S = k_cache.shape[2]
-    bs = min(block_s, S)
-    assert S % bs == 0
+    bs = _seq_block(S, block_s)
     ns = S // bs
-    nv = jnp.broadcast_to(jnp.asarray(n_valid, jnp.int32).reshape(-1, 1),
-                          (B, 1))
+    nv = jnp.broadcast_to(jnp.asarray(n_valid, jnp.int32).reshape(-1), (B,))
 
     kern = functools.partial(_kernel, bs=bs, ns=ns, scale=hd ** -0.5)
-    out = pl.pallas_call(
-        kern,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                       # n_valid
         grid=(B, Hkv, ns),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, i: (b, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, g, hd), lambda b, h, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, hd), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, bs, hd), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, g, hd), lambda b, h, i, nv: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bs, hd), lambda b, h, i, nv: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bs, hd), lambda b, h, i, nv: (b, h, i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda b, h, i: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, g, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, g, hd),
+                               lambda b, h, i, nv: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, hd), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+    )
+    return pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, g, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(nv, q, k_cache, v_cache)
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -171,7 +185,7 @@ def _paged_kernel(pt_ref, nv_ref, q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention_pallas(q, k_pool, v_pool, page_table, n_valid, *,
-                                  interpret: bool = True):
+                                  interpret: bool):
     """q: (B, Hkv, g, hd); pools: (P, Hkv, page_size, hd) page-major — one
     flat page pool shared by every batch row; page_table: (B, npg) int32 —
     row b's i-th entry is the pool page holding its logical positions
@@ -213,7 +227,7 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, page_table, n_valid, *,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(pt, nv, q, k_pool, v_pool)

@@ -18,10 +18,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30  # python float: avoids capturing a traced constant
 
-# jax 0.4.x names it TPUCompilerParams; 0.5+ renamed to CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
             causal: bool, bq: int, bk: int, nk: int, scale: float):
@@ -65,9 +61,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
 @functools.partial(jax.jit,
                    static_argnames=("causal", "block_q", "block_k",
                                     "interpret"))
-def flash_attention_pallas(q, k, v, *, causal: bool = True,
-                           block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True):
+def flash_attention_pallas(q, k, v, *, interpret: bool, causal: bool = True,
+                           block_q: int = 128, block_k: int = 128):
     """q,k,v: (B, S, H, hd) (equal head counts) → (B, S, H, hd)."""
     B, S, H, hd = q.shape
     bq, bk = min(block_q, S), min(block_k, S)
@@ -98,7 +93,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq, 1), jnp.float32),    # running max
             pltpu.VMEM((bq, 1), jnp.float32),    # normalizer
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -1,9 +1,11 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
-On TPU the compiled kernels run natively; everywhere else (this CPU
-container) the pure-jnp oracles from ``ref.py`` are the default and the
-kernels execute under ``interpret=True`` only in tests. Select with
-``impl="ref" | "pallas"`` or the ``REPRO_KERNELS`` env var.
+On TPU the compiled kernels run natively (``interpret=False``); on any
+other backend the pure-jnp oracles from ``ref.py`` are the default, and a
+forced ``impl="pallas"`` (or ``REPRO_KERNELS=pallas``) runs the kernels
+interpreted — how the CPU tests exercise them. The ``*_pallas`` wrappers
+take ``interpret`` with no default: this module is the one place that
+decides it, from the backend.
 """
 from __future__ import annotations
 

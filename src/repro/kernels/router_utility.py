@@ -24,14 +24,21 @@ def _kernel(h_ref, aw_ref, ab_ref, cw_ref, cb_ref, lam_ref, mask_ref,
     C = jax.lax.dot(h, cw_ref[...].astype(jnp.float32),
                     preferred_element_type=jnp.float32) + cb_ref[...]
     U = A - lam_ref[0, 0] * C + mask_ref[...]                # (BN, M)
-    choice_ref[...] = jnp.argmax(U, axis=1).astype(jnp.int32)
-    best_ref[...] = jnp.max(U, axis=1)
+    # (BN, 1) columns from keepdims lane reductions; ties keep the lowest
+    # model index, like jnp.argmax
+    best = jnp.max(U, axis=1, keepdims=True)
+    iota = jax.lax.broadcasted_iota(jnp.int32, U.shape, 1)
+    choice_ref[...] = jnp.min(jnp.where(U == best, iota, U.shape[1]), axis=1,
+                              keepdims=True)
+    best_ref[...] = best
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def router_utility_pallas(h, acc_w, acc_b, cost_w, cost_b, lam, *,
-                          block_n: int = 256, interpret: bool = True):
-    """h: (n, dh); heads (dh, M)/(M,); lam scalar → (choice (n,), best (n,))."""
+                          interpret: bool, block_n: int = 256):
+    """h: (n, dh); heads (dh, M)/(M,); lam scalar → (choice (n,), best (n,)).
+    Both outputs leave the kernel as (n, 1) columns: a 1-D output block
+    does not match XLA's tiling once n spans more than one block."""
     n, dh = h.shape
     M = acc_w.shape[1]
 
@@ -67,14 +74,14 @@ def router_utility_pallas(h, acc_w, acc_b, cost_w, cost_b, lam, *,
             pl.BlockSpec((1, m_p), whole),
         ],
         out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
+            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_p,), jnp.int32),
-            jax.ShapeDtypeStruct((n_p,), jnp.float32),
+            jax.ShapeDtypeStruct((n_p, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n_p, 1), jnp.float32),
         ],
         interpret=interpret,
     )(h_p, pad_w(acc_w), pad_b(acc_b), pad_w(cost_w), pad_b(cost_b),
       lam_arr, mask)
-    return choice[:n], best[:n]
+    return choice[:n, 0], best[:n, 0]

@@ -27,18 +27,21 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import sharding as shd
+from repro import compile_cache, sharding as shd
 from repro.config import INPUT_SHAPES, ModelConfig
 from repro.configs import get_config, list_archs
 from repro.launch import hlo_analysis as H
 from repro.launch import specs as SP
-from repro.launch.mesh import (HBM_BW, ICI_BW, PEAK_FLOPS_BF16,
-                               make_production_mesh)
+from repro.launch.mesh import make_production_mesh, peaks
 from repro.launch.steps import make_decode_step, make_prefill_step, make_train_step
 from repro.models import model as mdl
 from repro.train.optim import AdamWState
 
 RESULTS = pathlib.Path(__file__).resolve().parents[3] / "experiments" / "dryrun.jsonl"
+
+#: the chip the production mesh models: its roofline terms use this kind's
+#: published peaks (the compile itself runs on placeholder CPU devices)
+MODELED_DEVICE = "TPU v5 lite"
 
 # Decode shapes are skipped for encoder-only archs; long_500k uses the
 # sliding-window rolling cache for pure-attention archs (DESIGN.md §4).
@@ -152,12 +155,11 @@ def run_combo(arch: str, shape_name: str, *, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # jax 0.4.x returns [dict]
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
+    pk = peaks(MODELED_DEVICE)
     terms = H.roofline_terms(hlo, n_chips=n_chips,
-                             peak_flops=PEAK_FLOPS_BF16, hbm_bw=HBM_BW,
-                             ici_bw=ICI_BW)
+                             peak_flops=pk["flops_bf16"], hbm_bw=pk["hbm_bw"],
+                             ici_bw=pk["ici_bw"])
 
     params_shape = jax.eval_shape(
         functools.partial(mdl.init_params, cfg=cfg), jax.random.PRNGKey(0))
@@ -178,7 +180,7 @@ def run_combo(arch: str, shape_name: str, *, multi_pod: bool,
 
     hlo_flops_global = terms["hlo_flops_per_chip"] * n_chips
     rec.update(
-        status="ok", rolling=rolling,
+        status="ok", rolling=rolling, modeled_device=MODELED_DEVICE,
         lower_s=round(t_lower, 1), compile_s=round(t_compile, 1),
         n_params=n_params, n_active=n_active,
         state_bytes_global=state_bytes,
@@ -224,6 +226,7 @@ def append(rec, path=RESULTS):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

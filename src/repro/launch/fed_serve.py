@@ -16,8 +16,11 @@ from __future__ import annotations
 
 import argparse
 
+from repro import compile_cache
+
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--clients", type=int, default=6)
     ap.add_argument("--phases", type=int, default=2)

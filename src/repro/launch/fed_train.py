@@ -8,22 +8,17 @@ TPU-idiomatic replacement for a parameter server. All of that now lives
 behind ``repro.routers.fit_federated(..., mesh=...)``; this driver just
 builds the mesh, the data, and the router.
 
-Run standalone (simulates 8 devices on CPU):
-  PYTHONPATH=src python -m repro.launch.fed_train --clients 16 --rounds 10
+Runs on every device the process has; ``--clients`` must divide by their
+count. On CPU, simulate several with XLA_FLAGS:
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+      PYTHONPATH=src python -m repro.launch.fed_train --clients 16 --rounds 10
 """
-import os
-
-if __name__ == "__main__":  # only force fake devices when run as a driver
-    os.environ.setdefault(
-        "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
-# ruff: noqa: E402
 import argparse
 
 import jax
 from jax.sharding import Mesh
 
-from repro import routers, sharding as shd
+from repro import compile_cache, routers, sharding as shd
 from repro.config import FedConfig, RouterConfig
 from repro.core import policy
 from repro.data.partition import federated_split
@@ -45,6 +40,7 @@ def fedavg_distributed(key, data, rcfg: RouterConfig, fcfg: FedConfig, *,
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--clients", type=int, default=16)
     ap.add_argument("--rounds", type=int, default=10)

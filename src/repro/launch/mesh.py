@@ -26,7 +26,20 @@ def make_production_mesh(*, multi_pod: bool = False):
     return jax.make_mesh(shape, axes, devices=devices[:n])
 
 
-# Hardware constants for the roofline (TPU v5e per chip).
-PEAK_FLOPS_BF16 = 197e12      # FLOP/s
-HBM_BW = 819e9                # B/s
-ICI_BW = 50e9                 # B/s per link
+#: Per-chip peaks by ``device_kind``, for rooflines that MODEL a device
+#: (launch/dryrun.py compiles on placeholder CPU devices and states which
+#: chip it models). Source: Google Cloud documentation, "TPU v5e"
+#: (197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s ICI = 4 links x 50 GB/s).
+PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """Peaks of one chip kind; a kind not in the table is an error, never a
+    default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}"
+                       f" (known: {sorted(PEAKS)})") from None

@@ -11,12 +11,14 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.configs import get_config
 from repro.models import model as mdl
 from repro.serve.kv_cache import extend_cache
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")

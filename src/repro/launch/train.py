@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import get_config
 from repro.models import model as mdl
 from repro.train import checkpoint as ckpt
@@ -63,6 +64,7 @@ def train_loop(cfg, *, steps: int, batch: int, seq: int, lr: float = 3e-4,
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")

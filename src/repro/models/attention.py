@@ -21,7 +21,12 @@ import jax.numpy as jnp
 
 from repro.config import ModelConfig
 from repro.models import layers as L
-from repro.sharding import constrain
+from repro.sharding import constrain, kernel_call
+
+#: logical axes of the decode kernels' operands, for ``kernel_call``:
+#: queries and uniform caches (B, Hkv, g|S, hd), paged pools (P, Hkv, ps, hd)
+_ROWS_HEADS = ("batch", "heads4d", None, None)
+_PAGES = (None, "heads4d", None, None)
 
 
 def init_attn(key, cfg: ModelConfig) -> dict:
@@ -204,18 +209,19 @@ def attn_decode_step(p: dict, x: jnp.ndarray, cache: dict, pos: jnp.ndarray,
     Hkv, hd, g = cfg.n_kv_heads, cfg.head_dim, cfg.q_per_kv
     qg = q.reshape(B, Hkv, g, hd)
     from repro.kernels import ops as kops
-    if (kops._default_impl() == "pallas" and W % 8 == 0
-            and W % min(512, W) == 0):
+    if kops._default_impl() == "pallas":
         # TPU: stream the cache through the flash-decoding kernel (online
         # softmax, f32 accumulation, no f32 cache copy) — the same kernel
-        # the paged path dispatches to; the engine's uniform decode scan
-        # rides this too. The guards keep odd extend_cache lengths (e.g.
-        # S_bucket + max_new = 12: not sublane-aligned; 544: not a
-        # multiple of the 512 seq block) on the jnp path — pool caches
-        # are pow2 and always qualify. The kernel shares this path's
-        # dtype discipline (cache-dtype dots, f32 accumulation), so
-        # greedy tokens agree on bf16 caches (tests/test_kernels.py).
-        out = kops.decode_attention(qg, k_cache, v_cache, n_valid)
+        # family the paged path dispatches to; the engine's uniform decode
+        # scan rides this too. It tiles any W up to its seq block and any
+        # multiple of 8 beyond (extend_cache rounds to one). The kernel
+        # shares this path's dtype discipline (cache-dtype dots, f32
+        # accumulation), so greedy tokens agree on bf16 caches
+        # (tests/test_kernels.py).
+        out = kernel_call(kops.decode_attention,
+                          (qg, k_cache, v_cache, n_valid),
+                          (_ROWS_HEADS, _ROWS_HEADS, _ROWS_HEADS,
+                           ("batch",) if per_slot else ()))
         out = out.astype(v_cache.dtype)
     else:
         valid = (jnp.arange(W)[None, :] < n_valid[:, None] if per_slot
@@ -320,8 +326,10 @@ def attn_decode_step_paged(p: dict, x: jnp.ndarray, cache: dict,
     Hkv, hd, g = cfg.n_kv_heads, cfg.head_dim, cfg.q_per_kv
     qg = q.reshape(B, Hkv, g, hd)
     if kops._default_impl() == "pallas":
-        out = kops.paged_decode_attention(qg, k_cache, v_cache, page_table,
-                                          pos + 1)
+        out = kernel_call(kops.paged_decode_attention,
+                          (qg, k_cache, v_cache, page_table, pos + 1),
+                          (_ROWS_HEADS, _PAGES, _PAGES, ("batch", None),
+                           ("batch",)))
     else:
         # Deliberately the GATHER formulation, not the copy-free
         # segment-summed one (ref.paged_decode_attention_seg_ref, the CPU

@@ -20,22 +20,12 @@ The router load-balance auxiliary loss (Switch eq. 4) is returned alongside.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
 import jax.numpy as jnp
 
 from repro.config import ModelConfig
 from repro.models import layers as L
-from repro.sharding import constrain
-
-try:                                   # jax >= 0.5 exports it at top level
-    from jax import shard_map as _shard_map
-except ImportError:                    # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-# the "replication check" kwarg was renamed check_rep → check_vma
-_CHECK_KW = ("check_vma" if "check_vma"
-             in inspect.signature(_shard_map).parameters else "check_rep")
+from repro.sharding import constrain, shard_map
 
 
 def init_moe(key, cfg: ModelConfig) -> dict:
@@ -153,11 +143,9 @@ def _capacity_shard_map(p, xt, cfg: ModelConfig, cf: float):
         "wd": P(exp_ax, None, fsdp_ax),
     }
 
-    @functools.partial(
-        _shard_map, mesh=mesh,
-        in_specs=(w_specs, P(tok_ax, None)),
-        out_specs=(P(tok_ax, None), P()),
-        **{_CHECK_KW: False})
+    @functools.partial(shard_map, mesh=mesh,
+                       in_specs=(w_specs, P(tok_ax, None)),
+                       out_specs=(P(tok_ax, None), P()))
     def block(w, xt_loc):
         # FSDP all-gather of this layer's expert-shard weights
         wg = jax.lax.all_gather(w["wg"], fsdp_ax, axis=1, tiled=True)

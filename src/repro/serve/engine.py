@@ -446,7 +446,7 @@ class _Lane:
         #: dispatch is one mesh program), the model's own buffers otherwise
         self.params = pm.params
         if mesh is not None:
-            self.pool = shd.shard_kv_pool(self.pool, mesh)
+            self.pool = shd.shard_kv_pool(self.pool, mesh, paged=self.paged)
             self.params = shd.replicate(pm.params, mesh)
         self.free: List[int] = list(range(ecfg.slots))[::-1]
         self.active: Dict[int, _Active] = {}             # slot -> request

@@ -51,8 +51,13 @@ import numpy as np
 
 def extend_cache(cache, new_len: int):
     """Pad the seq dim of attention caches (leaf names k/v, dim 3 of the
-    stacked (L,B,Hkv,S,hd) head-major layout) up to new_len — used to
-    continue decoding from a prefill-produced cache."""
+    stacked (L,B,Hkv,S,hd) head-major layout) up to new_len, rounded up to
+    a multiple of 8 positions (the TPU sublane, so the Pallas decode kernel
+    can tile any such cache) — used to continue decoding from a
+    prefill-produced cache. Positions past the validity bound are masked,
+    so the rounding never changes a token."""
+    new_len = -(-new_len // 8) * 8
+
     def leaf(path, a):
         names = [p.key for p in path if hasattr(p, "key")]
         if names[-1] in ("k", "v"):

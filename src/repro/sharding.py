@@ -20,30 +20,20 @@ Two layers live here:
 from __future__ import annotations
 
 import contextlib
-import inspect
 from typing import Optional, Sequence, Union
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # moved out of experimental in newer jax
-    from jax import shard_map as _shard_map
-except ImportError:  # jax<=0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the "replication check" kwarg was renamed check_rep → check_vma
-_CHECK_KW = ("check_vma" if "check_vma"
-             in inspect.signature(_shard_map).parameters else "check_rep")
-
 Axis = Union[str, Sequence[str], None]
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs, *, check: bool = False):
-    """Version-compat ``shard_map``: one call site for the
-    check_rep→check_vma rename, shared by every sharded fit path."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check})
+    """``jax.shard_map`` with the replication check off by default — the
+    one call site every sharded path (fits, expert-parallel MoE) uses."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 _CURRENT: Optional[tuple] = None  # (mesh, rules: dict[str, Axis])
 
@@ -104,6 +94,21 @@ def constrain(x: jax.Array, logical: Sequence) -> jax.Array:
     mesh, _ = _CURRENT
     spec = resolve_spec(logical, x.shape)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
+def kernel_call(f, args: Sequence, logical: Sequence[Sequence]):
+    """Call a Pallas kernel wrapper ``f(*args)`` under the active rules.
+    XLA cannot partition a Mosaic kernel, so on a mesh the call runs per
+    device under ``shard_map``: each operand is split along the mesh axes
+    its logical names resolve to (``resolve_spec`` — non-dividing dims
+    replicate), and the output is laid out like the first operand. Outside
+    any ``use_rules`` context it is ``f(*args)``."""
+    if _CURRENT is None:
+        return f(*args)
+    mesh, _ = _CURRENT
+    specs = tuple(resolve_spec(lg, jax.numpy.shape(a))
+                  for lg, a in zip(logical, args))
+    return shard_map(f, mesh, specs, specs[0])(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -206,13 +211,16 @@ def shard_clients(data, mesh: Mesh):
         data)
 
 
-def kv_pool_spec(leaf_ndim: int, mesh: Mesh, leaf_shape=None) -> P:
+def kv_pool_spec(leaf_ndim: int, mesh: Mesh, leaf_shape=None, *,
+                 paged: bool = False) -> P:
     """PartitionSpec for a KV-pool leaf: 5-D pool leaves are
     ``(n_units, slots|pages, Hkv, seq, hd)`` — shard the slot dim over
     ``"data"`` and/or the head dim over ``"heads"`` when the mesh carries
-    those axes and the dim divides; everything else replicates. Non-5-D
-    leaves (SSM states etc.) shard their dim-1 batch over ``"data"``
-    only."""
+    those axes and the dim divides; everything else replicates. A paged
+    pool's page dim always replicates: any decode row may read any page,
+    so a page-sharded pool would be gathered whole into every device's
+    kernel call. Non-5-D leaves (SSM states etc.) shard their dim-1 batch
+    over ``"data"`` only."""
     axes = dict(mesh.shape)
 
     def fits(dim_size, ax):
@@ -221,17 +229,17 @@ def kv_pool_spec(leaf_ndim: int, mesh: Mesh, leaf_shape=None) -> P:
 
     shape = leaf_shape if leaf_shape is not None else [None] * leaf_ndim
     spec = [None] * leaf_ndim
-    if leaf_ndim >= 2 and fits(shape[1], "data"):
+    if leaf_ndim >= 2 and not paged and fits(shape[1], "data"):
         spec[1] = "data"
     if leaf_ndim == 5 and fits(shape[2], "heads"):
         spec[2] = "heads"
     return P(*spec)
 
 
-def shard_kv_pool(pool, mesh: Mesh):
+def shard_kv_pool(pool, mesh: Mesh, *, paged: bool = False):
     """device_put a KV pool (slot or page regime) with each leaf sharded
     per ``kv_pool_spec`` — the engine's mesh placement."""
     return jax.tree.map(
-        lambda a: jax.device_put(
-            a, NamedSharding(mesh, kv_pool_spec(a.ndim, mesh, a.shape))),
+        lambda a: jax.device_put(a, NamedSharding(
+            mesh, kv_pool_spec(a.ndim, mesh, a.shape, paged=paged))),
         pool)
