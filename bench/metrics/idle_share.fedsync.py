@@ -1,0 +1,6 @@
+"""idle_share.fedsync: see ``bench.readers.idle_share``."""
+from bench import readers
+
+
+def read(run):
+    return readers.idle_share(run)

@@ -1,0 +1,6 @@
+"""idle_share.saturate: see ``bench.readers.idle_share``."""
+from bench import readers
+
+
+def read(run):
+    return readers.idle_share(run)

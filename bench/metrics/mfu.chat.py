@@ -1,0 +1,6 @@
+"""mfu.chat: see ``bench.readers.mfu``."""
+from bench import readers
+
+
+def read(run):
+    return readers.mfu(run)
