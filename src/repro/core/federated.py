@@ -578,8 +578,9 @@ def _make_scan_fit(round_fn, rounds: int, *, donate: bool = True):
     (params, advanced key, per-round losses) so chunked-eval fits can
     thread the key across chunks. ``staleness`` is an optional extra
     operand; the None default is resolved at trace time, so 3-arg callers
-    are bit-for-bit the legacy scan."""
-    def run(params, key, data, staleness=None):
+    are bit-for-bit the legacy scan. The program is named ``fedavg_fit``,
+    so a profiler trace shows it as ``jit_fedavg_fit``."""
+    def fedavg_fit(params, key, data, staleness=None):
         def body(carry, _):
             params, key = carry
             key, k_r = jax.random.split(key)
@@ -594,7 +595,7 @@ def _make_scan_fit(round_fn, rounds: int, *, donate: bool = True):
                                              length=rounds)
         return params, key, losses
 
-    return jax.jit(run, donate_argnums=(0,) if donate else ())
+    return jax.jit(fedavg_fit, donate_argnums=(0,) if donate else ())
 
 
 def _round_partial(rcfg, fcfg, optimizer, max_steps, full_batch, dp_sigma,

@@ -125,7 +125,13 @@ class FedLoop:
         Exactly ``routers.fit_federated(server.router, harvested, fcfg)``
         from the live router's state — deterministically harvested buffers
         therefore reproduce an offline fit bit-for-bit (test-enforced).
-        Returns the fit history."""
+        Returns the fit history. Host spans: ``fed.sync`` the whole call,
+        ``fed.stack`` the harvest stacked into the client slab, ``fed.fit``
+        the fit through the fetch of its losses."""
+        with jax.profiler.TraceAnnotation("fed.sync"):
+            return self._sync(key)
+
+    def _sync(self, key) -> dict:
         harvest = self.server.harvest
         if len(harvest) == 0:
             raise ValueError("sync() with empty harvest buffers would "
@@ -134,8 +140,10 @@ class FedLoop:
                              "min_samples)")
         if key is None:
             self._key, key = jax.random.split(self._key)
-        data = harvest.as_federated_data(
-            pad_to=harvest.capacity if self.cfg.pad_to_capacity else None)
+        with jax.profiler.TraceAnnotation("fed.stack"):
+            data = harvest.as_federated_data(
+                pad_to=harvest.capacity if self.cfg.pad_to_capacity
+                else None)
         kw = {} if self.aggregator is None else {
             "aggregator": self.aggregator}
         if self.cfg.cohort is not None:
@@ -157,9 +165,10 @@ class FedLoop:
             kw["mesh"] = self.cfg.mesh
             if self.server.router.parametric:
                 kw["donate_data"] = True
-        new_router, hist = routers.fit_federated(
-            self.server.router, data, self.fcfg, key=key,
-            rounds=self.cfg.rounds_per_sync, **kw)
+        with jax.profiler.TraceAnnotation("fed.fit"):
+            new_router, hist = routers.fit_federated(
+                self.server.router, data, self.fcfg, key=key,
+                rounds=self.cfg.rounds_per_sync, **kw)
         self.server.swap_router_state(new_router.state)
         self._note_sync()
         # snapshot the engine's resilience counters alongside each sync so

@@ -38,10 +38,18 @@ KV memory comes in two regimes (``EngineConfig.page_size``):
   for benchmarks): every slot reserves a full ``max_seq`` region.
 
 Every jitted function is built once per (model config, static shape) and
-cached at module level; warm traffic compiles nothing (appends to
-``TRACE_LOG`` are per jit *trace*, and tests pin them flat — including
-paged decode across mixed per-request page counts, whose shapes are
-static ``(slots, max_pages)``).
+cached at module level, and named by the function handed to ``jax.jit``
+(``engine_prefill``, ``engine_write_pages``, ``engine_decode_paged``, ...),
+which a profiler trace shows as the program's module (``jit_<name>``).
+Host spans (``jax.profiler.TraceAnnotation``, about a microsecond each
+when no profiler runs) bracket the work on the same clock as the device:
+``engine.step`` the whole step, ``engine.prefill`` one coalesced bucket
+group from dispatch through the fetch of its first tokens, and
+``engine.decode`` one lane's chunk from dispatch through the fetch of its
+tokens. Warm traffic compiles nothing (appends to ``TRACE_LOG`` are per
+jit *trace*, and tests pin them flat — including paged decode across
+mixed per-request page counts, whose shapes are static
+``(slots, max_pages)``).
 
 Greedy decode is prefix-stable, so a request's tokens are bit-identical
 to the single-request scan path (``RoutedServer.generate(engine=False)``
@@ -234,7 +242,7 @@ def _prefill_fn(cfg: ModelConfig):
     lanes admit one request at a time) or a (B,) vector (coalesced paged
     admission: same-bucket requests of different true lengths batched into
     one dispatch, each row unembedded at its own last position)."""
-    def prefill(params, toks, last_pos):
+    def engine_prefill(params, toks, last_pos):
         TRACE_LOG.append(("engine_prefill", cfg.name, toks.shape))
         logits, _, cache = mdl.forward(params, cfg, tokens=toks,
                                        logits_last_only=True,
@@ -242,7 +250,7 @@ def _prefill_fn(cfg: ModelConfig):
                                        return_cache=True, q_chunk=64)
         tok0 = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         return tok0, cache
-    return jax.jit(prefill)
+    return jax.jit(engine_prefill)
 
 
 @functools.lru_cache(maxsize=None)
@@ -250,11 +258,11 @@ def _admit_fn(cfg: ModelConfig):
     """Write a prefill cache into one pool slot. The pool argument is
     donated: admission mutates the persistent buffers in place instead of
     copying the whole pool per request."""
-    def admit(pool, prefill_cache, slot):
+    def engine_admit_slot(pool, prefill_cache, slot):
         TRACE_LOG.append(("engine_admit", cfg.name,
                           jax.tree.leaves(prefill_cache)[0].shape))
         return write_slot(pool, prefill_cache, slot)
-    return jax.jit(admit, donate_argnums=(0,))
+    return jax.jit(engine_admit_slot, donate_argnums=(0,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -263,12 +271,12 @@ def _write_pages_fn(cfg: ModelConfig):
     argument is donated: admission mutates the persistent page buffers in
     place instead of copying the pool per batch. One trace per
     (B_b, S_b, n_pp) admission shape."""
-    def write(pool, prefill_cache, pages_mat):
+    def engine_write_pages(pool, prefill_cache, pages_mat):
         TRACE_LOG.append(("engine_write_pages", cfg.name,
                           jax.tree.leaves(prefill_cache)[0].shape,
                           pages_mat.shape))
         return write_prefill_pages(pool, prefill_cache, pages_mat)
-    return jax.jit(write, donate_argnums=(0,))
+    return jax.jit(engine_write_pages, donate_argnums=(0,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -278,7 +286,7 @@ def _chunk_paged_fn(cfg: ModelConfig, chunk: int):
     max_pages) page table. The table's shape is static, so mixed
     per-request page counts never retrace; the pool is donated —
     steady-state decode reuses the page buffers."""
-    def run(params, cache, page_table, tok, pos):
+    def engine_decode_paged(params, cache, page_table, tok, pos):
         TRACE_LOG.append(("engine_chunk_paged", cfg.name, tok.shape,
                           page_table.shape, chunk))
 
@@ -293,7 +301,7 @@ def _chunk_paged_fn(cfg: ModelConfig, chunk: int):
         (tok, pos, cache), out = jax.lax.scan(body, (tok, pos, cache), None,
                                               length=chunk)
         return cache, tok, pos, out.T                     # out: (B, chunk)
-    return jax.jit(run, donate_argnums=(1,))
+    return jax.jit(engine_decode_paged, donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -303,7 +311,7 @@ def _chunk_fn(cfg: ModelConfig, chunk: int):
     at each step (same emission order as the gateway scan), the slot
     cache (donated — steady-state decode reuses the pool buffers), and the
     advanced (tok, pos) carry."""
-    def run(params, cache, tok, pos):
+    def engine_decode(params, cache, tok, pos):
         TRACE_LOG.append(("engine_chunk", cfg.name, tok.shape, chunk))
 
         def body(carry, _):
@@ -316,7 +324,7 @@ def _chunk_fn(cfg: ModelConfig, chunk: int):
         (tok, pos, cache), out = jax.lax.scan(body, (tok, pos, cache), None,
                                               length=chunk)
         return cache, tok, pos, out.T                     # out: (B, chunk)
-    return jax.jit(run, donate_argnums=(1,))
+    return jax.jit(engine_decode, donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -326,7 +334,7 @@ def _draft_fn(cfg: ModelConfig, k: int):
     generated tokens instead of the fed ones — the drafted window the
     target's verify step will judge. One trace per (draft config, k);
     the draft pool is donated like every steady-state cache."""
-    def run(params, cache, tok, pos):
+    def engine_draft(params, cache, tok, pos):
         TRACE_LOG.append(("engine_draft", cfg.name, tok.shape, k))
 
         def body(carry, _):
@@ -339,7 +347,7 @@ def _draft_fn(cfg: ModelConfig, k: int):
         (tok, pos, cache), drafted = jax.lax.scan(body, (tok, pos, cache),
                                                   None, length=k)
         return cache, drafted.T                           # (B, k)
-    return jax.jit(run, donate_argnums=(1,))
+    return jax.jit(engine_draft, donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -350,12 +358,12 @@ def _verify_fn(cfg: ModelConfig, T: int):
     chain would emit after the first j drafted tokens, so the host-side
     accept loop just compares it against the draft. One trace per
     (model config, T); the pool is donated."""
-    def run(params, cache, tok, pos):
+    def engine_verify(params, cache, tok, pos):
         TRACE_LOG.append(("engine_verify", cfg.name, tok.shape))
         logits, cache = mdl.decode_verify(params, cache, cfg,
                                           tokens=tok, pos=pos)
         return cache, jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return jax.jit(run, donate_argnums=(1,))
+    return jax.jit(engine_verify, donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=None)
@@ -363,7 +371,7 @@ def _verify_paged_fn(cfg: ModelConfig, T: int):
     """Paged twin of ``_verify_fn`` (``mdl.decode_verify_paged``): the
     (slots, max_pages) table shape is static, so mixed per-request page
     counts never retrace — same guarantee as ``_chunk_paged_fn``."""
-    def run(params, cache, page_table, tok, pos):
+    def engine_verify_paged(params, cache, page_table, tok, pos):
         TRACE_LOG.append(("engine_verify_paged", cfg.name, tok.shape,
                           page_table.shape))
         logits, cache = mdl.decode_verify_paged(params, cache, cfg,
@@ -371,7 +379,7 @@ def _verify_paged_fn(cfg: ModelConfig, T: int):
                                                 page_table=page_table,
                                                 pos=pos)
         return cache, jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return jax.jit(run, donate_argnums=(1,))
+    return jax.jit(engine_verify_paged, donate_argnums=(1,))
 
 
 # ---------------------------------------------------------------------------
@@ -428,8 +436,9 @@ class _Lane:
     """Per-model engine state: the KV pool (paged or uniform) + host-side
     slot/page bookkeeping."""
 
-    def __init__(self, pm, ecfg: EngineConfig, mesh=None):
+    def __init__(self, pm, ecfg: EngineConfig, mesh=None, idx: int = 0):
         self.pm = pm
+        self.idx = idx                 #: model pool index (span args)
         self.ecfg = ecfg
         self.mesh = mesh
         self.paged = bool(ecfg.page_size)
@@ -547,9 +556,17 @@ class ServeEngine:
         self.spec_drafted = 0
         self.spec_accepted = 0
         self.spec_rejected = 0
-        #: queue-wait per admitted request (submit → prefill dispatched),
-        #: seconds; bounded like TRACE_LOG so long-running servers don't
-        #: leak. benchmarks/perf_suite.bench_paged reads the p99.
+        #: paged decode accounting (exact, host-side), per decode chunk:
+        #: pages holding a valid position of an active row, sum over rows
+        #: of ceil((pos + chunk) / page_size), and pages of the table the
+        #: chunk program is handed, slots * max_pages. Their ratio is the
+        #: share of the mapped table that holds work.
+        self.decode_pages_valid = 0
+        self.decode_pages_mapped = 0
+        #: queue-wait per admitted request (submit → taken off the queue
+        #: by admission), seconds; bounded like TRACE_LOG so long-running
+        #: servers don't leak. benchmarks/perf_suite.bench_paged reads the
+        #: p99.
         self.admission_lat: Deque[float] = collections.deque(maxlen=65536)
         #: high-water mark of concurrently admitted requests, sampled at
         #: every chunk boundary between admission and decode (completions
@@ -683,8 +700,8 @@ class ServeEngine:
         self._next_rid += 1
         lane = self._lanes.get(int(model_idx))
         if lane is None:
-            lane = self._lanes[int(model_idx)] = _Lane(pm, self.ecfg,
-                                                       self.mesh)
+            lane = self._lanes[int(model_idx)] = _Lane(
+                pm, self.ecfg, self.mesh, idx=int(model_idx))
         pend = _Pending(rid, toks, max_new, t_submit=time.perf_counter(),
                         deadline=(self._steps + int(deadline)
                                   if deadline is not None else None),
@@ -800,7 +817,9 @@ class ServeEngine:
                 "spec_rounds": self.spec_rounds,
                 "spec_drafted": self.spec_drafted,
                 "spec_accepted": self.spec_accepted,
-                "spec_rejected": self.spec_rejected}
+                "spec_rejected": self.spec_rejected,
+                "decode_pages_valid": self.decode_pages_valid,
+                "decode_pages_mapped": self.decode_pages_mapped}
 
     def _expire(self, lane: _Lane) -> None:
         """EXPIRE every request (active or queued) whose deadline has
@@ -837,19 +856,21 @@ class ServeEngine:
         ``EngineConfig.done_buffer`` of them, oldest evicted first, so a
         server that consumes step()'s return value and never drains can
         run forever without growing memory."""
-        for lane in self._lanes.values():
-            self._expire(lane)
-        for lane in self._lanes.values():
-            self._admit(lane)
-        self.peak_active = max(self.peak_active, self.n_active())
-        for lane in self._lanes.values():
-            if lane.active and lane.paged and self.ecfg.reserve == "initial":
-                self._grow_for_chunk(lane)
-            if lane.active:
-                if self.ecfg.spec_k:
-                    self._decode_spec_round(lane)
-                else:
-                    self._decode_chunk(lane)
+        with jax.profiler.TraceAnnotation("engine.step"):
+            for lane in self._lanes.values():
+                self._expire(lane)
+            for lane in self._lanes.values():
+                self._admit(lane)
+            self.peak_active = max(self.peak_active, self.n_active())
+            for lane in self._lanes.values():
+                if (lane.active and lane.paged
+                        and self.ecfg.reserve == "initial"):
+                    self._grow_for_chunk(lane)
+                if lane.active:
+                    if self.ecfg.spec_k:
+                        self._decode_spec_round(lane)
+                    else:
+                        self._decode_chunk(lane)
         self._steps += 1
         finished = self._events
         self._events = []
@@ -1028,6 +1049,7 @@ class ServeEngine:
         cfg = lane.pm.cfg
         while lane.free and lane.queue:
             req = lane.queue.popleft()
+            self.admission_lat.append(time.perf_counter() - req.t_submit)
             slot = lane.free.pop()
             full = self._full_prompt(req)
             S = len(full)
@@ -1041,7 +1063,6 @@ class ServeEngine:
                 lane.pool = _admit_fn(cfg)(lane.pool, kv, jnp.int32(slot))
             if self.ecfg.spec_k:
                 self._admit_draft(lane, slot, req.draft, full)
-            self.admission_lat.append(time.perf_counter() - req.t_submit)
             lane.tok[slot] = int(tok0[0])
             lane.pos[slot] = S          # first decode token writes K/V at S
             lane.active[slot] = self._activate(req, S)
@@ -1080,6 +1101,7 @@ class ServeEngine:
                 self._preempt(lane, victim)
                 continue
             lane.queue.popleft()
+            self.admission_lat.append(time.perf_counter() - req.t_submit)
             slot = lane.free.pop()
             pages = lane.pt.alloc(slot, need)
             admitted.append((req, slot, S, S_b, pages))
@@ -1100,19 +1122,19 @@ class ServeEngine:
                 toks_p[r, :S] = self._full_prompt(req)
                 last[r] = S - 1
                 pages_mat[r] = pages[:n_pp]
-            with self._rules():
-                tok0, kv = _prefill_fn(cfg)(lane.params,
-                                            jnp.asarray(toks_p),
-                                            jnp.asarray(last))
-                lane.pool = _write_pages_fn(cfg)(lane.pool, kv,
-                                                 jnp.asarray(pages_mat))
-            tok0 = np.asarray(tok0)
-            now = time.perf_counter()
+            with jax.profiler.TraceAnnotation(
+                    "engine.prefill", lane=lane.idx, rows=B, bucket=S_b):
+                with self._rules():
+                    tok0, kv = _prefill_fn(cfg)(lane.params,
+                                                jnp.asarray(toks_p),
+                                                jnp.asarray(last))
+                    lane.pool = _write_pages_fn(cfg)(lane.pool, kv,
+                                                     jnp.asarray(pages_mat))
+                tok0 = np.asarray(tok0)
             for r, (req, slot, S, _, pages) in enumerate(items):
                 if self.ecfg.spec_k:
                     self._admit_draft(lane, slot, req.draft,
                                       self._full_prompt(req))
-                self.admission_lat.append(now - req.t_submit)
                 lane.tok[slot] = int(tok0[r])
                 lane.pos[slot] = S      # first decode token writes K/V at S
                 lane.active[slot] = self._activate(req, S)
@@ -1218,16 +1240,24 @@ class ServeEngine:
 
     def _decode_chunk(self, lane: _Lane) -> None:
         cfg, ecfg = lane.pm.cfg, self.ecfg
-        with self._rules():
-            if lane.paged:
-                lane.pool, tok, pos, out = _chunk_paged_fn(cfg, ecfg.chunk)(
-                    lane.params, lane.pool, jnp.asarray(lane.pt.table),
-                    jnp.asarray(lane.tok), jnp.asarray(lane.pos))
-            else:
-                lane.pool, tok, pos, out = _chunk_fn(cfg, ecfg.chunk)(
-                    lane.params, lane.pool, jnp.asarray(lane.tok),
-                    jnp.asarray(lane.pos))
-        out = np.asarray(out)
+        if lane.paged:
+            rows = list(lane.active)
+            self.decode_pages_valid += int(np.sum(
+                -(-(lane.pos[rows] + ecfg.chunk) // ecfg.page_size)))
+            self.decode_pages_mapped += ecfg.slots * lane.pt.max_pages
+        with jax.profiler.TraceAnnotation("engine.decode", lane=lane.idx,
+                                          rows=len(lane.active)):
+            with self._rules():
+                if lane.paged:
+                    lane.pool, tok, pos, out = _chunk_paged_fn(
+                        cfg, ecfg.chunk)(
+                        lane.params, lane.pool, jnp.asarray(lane.pt.table),
+                        jnp.asarray(lane.tok), jnp.asarray(lane.pos))
+                else:
+                    lane.pool, tok, pos, out = _chunk_fn(cfg, ecfg.chunk)(
+                        lane.params, lane.pool, jnp.asarray(lane.tok),
+                        jnp.asarray(lane.pos))
+            out = np.asarray(out)
         active_mask = np.zeros((ecfg.slots,), bool)
         active_mask[list(lane.active)] = True
         # free slots keep (tok=0, pos=0). Their garbage K/V writes are safe

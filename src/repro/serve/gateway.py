@@ -205,16 +205,17 @@ class RoutedServer:
 
     @staticmethod
     def _make_route_fn(router: Router):
-        def route_fn(state, x, lam):
+        def gateway_route(state, x, lam):
             TRACE_LOG.append(("route", type(router).__name__, x.shape))
             return router.with_state(state).route(x, lam)
-        return jax.jit(route_fn)
+        return jax.jit(gateway_route)
 
     def _route_x(self, x: np.ndarray, lam: float) -> np.ndarray:
         """Route pre-encoded query embeddings x: (B, d_emb) → (B,) model
         indices. The jitted decision fn takes state and λ as traced
         arguments, so hot-swapped router state and per-request λ hit the
-        same compiled program (TRACE_LOG-pinned)."""
+        same compiled program (TRACE_LOG-pinned). The ``gateway.route``
+        span runs from dispatch through the fetch of the choices."""
         if self.router is not self._route_fn_router:
             self._route_fn = self._make_route_fn(self.router)
             self._route_fn_router = self.router
@@ -223,9 +224,11 @@ class RoutedServer:
         if B_b != B:
             x = np.concatenate([x, np.zeros((B_b - B, x.shape[1]),
                                             x.dtype)])
-        choice = self._route_fn(self.router.state, jnp.asarray(x),
-                                jnp.float32(lam))
-        return np.asarray(choice)[:B]
+        with jax.profiler.TraceAnnotation("gateway.route"):
+            choice = self._route_fn(self.router.state, jnp.asarray(x),
+                                    jnp.float32(lam))
+            choice = np.asarray(choice)
+        return choice[:B]
 
     def route(self, prompts: List[str], lam: float) -> np.ndarray:
         return self._route_x(encode(prompts, self.d_emb), lam)
