@@ -87,9 +87,9 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, n_valid, *,
                            impl: str | None = None):
     """Decode attention against the paged KV pool (serve/kv_cache): each
     batch row attends the pages its page-table row names. On TPU the Pallas
-    kernel DMAs pages via scalar prefetch; the CPU fallback runs the
-    segment-summed formulation (ref.paged_decode_attention_seg_ref), which
-    reads the pools in place instead of materializing each row's
+    kernel DMAs each row's valid pages, block by block; the CPU fallback
+    runs the segment-summed formulation (ref.paged_decode_attention_seg_ref),
+    which reads the pools in place instead of materializing each row's
     (B, Hkv, npg·ps, hd) gathered copy. The gather-based oracle
     (ref.paged_decode_attention_ref) stays the parity ground truth in
     tests for both this fallback and the Pallas kernel."""

@@ -300,7 +300,7 @@ def attn_decode_step_paged(p: dict, x: jnp.ndarray, cache: dict,
     The new K/V lands at (page_table[b, pos_b // ps], pos_b % ps); rows
     whose table entry is the trash page (index 0 by serve/kv_cache
     convention) scatter harmlessly there. On TPU attention runs the
-    scalar-prefetch Pallas kernel (``paged_decode_attention_pallas`` —
+    Pallas kernel (``paged_decode_attention_pallas`` — each row's valid
     pages DMA'd by table lookup, gather never materialized); elsewhere it
     gathers the pages and reuses ``attn_decode_step``'s exact einsum
     discipline — dot in the cache dtype with f32 accumulation — so engine

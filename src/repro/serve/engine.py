@@ -114,6 +114,7 @@ import numpy as np
 
 import repro.sharding as shd
 from repro.config import ModelConfig
+from repro.kernels.decode_attention import paged_block_pages
 from repro.models import model as mdl
 from repro.serve.kv_cache import (PageTable, alloc_draft_pool,
                                   alloc_page_pool, alloc_slot_pool,
@@ -457,6 +458,14 @@ class _Lane:
         if mesh is not None:
             self.pool = shd.shard_kv_pool(self.pool, mesh, paged=self.paged)
             self.params = shd.replicate(pm.params, mesh)
+        if self.paged:
+            # (units, pages, Hkv, page_size, hd): the kernel's blocks hold
+            # every KV head of its shard of the pool
+            leaf = jax.tree.leaves(self.pool)[0]
+            hkv, ps, hd = leaf.sharding.shard_shape(leaf.shape)[2:]
+            #: table entries the paged decode kernel fetches per block
+            self.block_pages = paged_block_pages(
+                self.pt.max_pages, hkv * ps * hd * leaf.dtype.itemsize)
         self.free: List[int] = list(range(ecfg.slots))[::-1]
         self.active: Dict[int, _Active] = {}             # slot -> request
         self.queue: Deque[_Pending] = collections.deque()
@@ -560,9 +569,13 @@ class ServeEngine:
         #: pages holding a valid position of an active row, sum over rows
         #: of ceil((pos + chunk) / page_size), and pages of the table the
         #: chunk program is handed, slots * max_pages. Their ratio is the
-        #: share of the mapped table that holds work.
+        #: share of the mapped table that holds work. decode_pages_read:
+        #: pages the paged kernel fetches, every slot's valid pages at the
+        #: chunk's end (inactive slots sit at pos 0) rounded up to whole
+        #: blocks of the lane's ``block_pages``, at most max_pages a row.
         self.decode_pages_valid = 0
         self.decode_pages_mapped = 0
+        self.decode_pages_read = 0
         #: queue-wait per admitted request (submit → taken off the queue
         #: by admission), seconds; bounded like TRACE_LOG so long-running
         #: servers don't leak. benchmarks/perf_suite.bench_paged reads the
@@ -819,7 +832,8 @@ class ServeEngine:
                 "spec_accepted": self.spec_accepted,
                 "spec_rejected": self.spec_rejected,
                 "decode_pages_valid": self.decode_pages_valid,
-                "decode_pages_mapped": self.decode_pages_mapped}
+                "decode_pages_mapped": self.decode_pages_mapped,
+                "decode_pages_read": self.decode_pages_read}
 
     def _expire(self, lane: _Lane) -> None:
         """EXPIRE every request (active or queued) whose deadline has
@@ -1242,9 +1256,12 @@ class ServeEngine:
         cfg, ecfg = lane.pm.cfg, self.ecfg
         if lane.paged:
             rows = list(lane.active)
-            self.decode_pages_valid += int(np.sum(
-                -(-(lane.pos[rows] + ecfg.chunk) // ecfg.page_size)))
+            pages = -(-(lane.pos + ecfg.chunk) // ecfg.page_size)
+            self.decode_pages_valid += int(np.sum(pages[rows]))
             self.decode_pages_mapped += ecfg.slots * lane.pt.max_pages
+            bp, mp = lane.block_pages, lane.pt.max_pages
+            self.decode_pages_read += int(np.sum(np.minimum(
+                -(-np.minimum(pages, mp) // bp) * bp, mp)))
         with jax.profiler.TraceAnnotation("engine.decode", lane=lane.idx,
                                           rows=len(lane.active)):
             with self._rules():

@@ -344,23 +344,41 @@ def test_decode_attention_ragged_validity_including_empty_rows():
 
 @pytest.mark.parametrize("B,Hkv,g,ps,npg,P", [(2, 2, 2, 8, 4, 12),
                                               (3, 1, 4, 16, 2, 5),
-                                              (1, 2, 1, 32, 3, 4)])
+                                              (1, 2, 1, 32, 3, 4),
+                                              (2, 8, 4, 16, None, 40),
+                                              (2, 2, 6, 16, None, 120)])
 def test_paged_decode_attention_matches_oracle(B, Hkv, g, ps, npg, P):
-    """Scalar-prefetch paged kernel == gather oracle over random page
-    tables (trash-page entries included via short validity bounds)."""
-    from repro.kernels.decode_attention import paged_decode_attention_pallas
+    """Paged kernel == gather oracle: B rows over random page tables and
+    bounds, then five rows placed around the kernel's page block — bounds
+    0, 1, exactly one block, one past a block, and past the table — whose
+    entries past their valid pages name the trash page 0. ``npg=None``
+    makes the table one and a half blocks wide, so its last block is cut
+    short by the table's end."""
+    from repro.kernels.decode_attention import (paged_block_pages,
+                                                paged_decode_attention_pallas)
     hd = 32
+    page_bytes = Hkv * ps * hd * 4
+    if npg is None:
+        npg = paged_block_pages(1 << 20, page_bytes) * 3 // 2
+        assert npg % paged_block_pages(npg, page_bytes)
+    bk = paged_block_pages(npg, page_bytes) * ps
+    edge = np.array([0, 1, bk, bk + 1, npg * ps + ps])
     ks = jax.random.split(jax.random.PRNGKey(B * ps + npg), 3)
-    q = jax.random.normal(ks[0], (B, Hkv, g, hd))
+    q = jax.random.normal(ks[0], (B + len(edge), Hkv, g, hd))
     kp = jax.random.normal(ks[1], (P, Hkv, ps, hd))
     vp = jax.random.normal(ks[2], (P, Hkv, ps, hd))
     rng = np.random.default_rng(0)
-    pt = jnp.asarray(rng.integers(0, P, size=(B, npg)), jnp.int32)
-    nv = jnp.asarray(rng.integers(0, npg * ps + 1, size=(B,)), jnp.int32)
+    pt = rng.integers(0, P, size=(B + len(edge), npg))
+    for r, n in enumerate(edge, B):
+        pt[r, -(-n // ps):] = 0
+    pt = jnp.asarray(pt, jnp.int32)
+    nv = jnp.asarray(np.concatenate(
+        [rng.integers(0, npg * ps + 1, size=(B,)), edge]), jnp.int32)
     got = paged_decode_attention_pallas(q, kp, vp, pt, nv, interpret=True)
     want = ref.paged_decode_attention_ref(q, kp, vp, pt, nv)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5,
                                atol=2e-5)
+    assert np.all(np.asarray(got)[B] == 0.0)
 
 
 @pytest.mark.parametrize("B,Hkv,g,ps,npg,P", [(2, 2, 2, 8, 4, 12),

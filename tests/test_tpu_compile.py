@@ -13,6 +13,7 @@ one process at a time may load the TPU compiler library, so every worker
 collects the same tests and only the one that runs this file loads it.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +61,7 @@ def _compile(fn, one_chip, *shapes):
             for s, dt in shapes]
     hlo = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo
+    return hlo
 
 
 @pytest.mark.parametrize("S", [256, 12, 520, 1000])
@@ -72,13 +74,35 @@ def test_uniform_decode_compiles(one_chip, S):
              ((8, HKV, S, HD), BF16), ((8,), jnp.int32))
 
 
-def test_paged_decode_compiles(one_chip):
-    """The engine's decode: 16-position pages, a 16-page table per row."""
+# the served cells' paged lanes: (rows, KV heads, queries per KV head),
+# pool pages (with the trash page) and table width, 16-position pages
+PAGED = {"qwen3-8b": (8, 8, 4, 769, 96), "qwen2-1.5b-chat": (32, 2, 6, 1025, 80)}
+
+
+def _paged_shapes(B, hkv, g, pages, npg):
+    return (((B, hkv, g, HD), BF16), ((pages, hkv, 16, HD), BF16),
+            ((pages, hkv, 16, HD), BF16), ((B, npg), jnp.int32),
+            ((B,), jnp.int32))
+
+
+@pytest.mark.parametrize("lane", sorted(PAGED))
+def test_paged_decode_compiles(one_chip, lane):
+    """The engine's decode at the benchmark's sizes: saturate's qwen3 lane
+    and chat's qwen2 lane."""
     _compile(lambda q, k, v, pt, nv: paged_decode_attention_pallas(
                  q, k, v, pt, nv, interpret=False), one_chip,
-             ((8, HKV, G, HD), BF16), ((129, HKV, 16, HD), BF16),
-             ((129, HKV, 16, HD), BF16), ((8, 16), jnp.int32),
-             ((8,), jnp.int32))
+             *_paged_shapes(*PAGED[lane]))
+
+
+def test_paged_decode_kernel_keeps_its_trace_name(one_chip):
+    """The compiled custom call is named with ``paged_decode_attention``:
+    the benchmark finds the kernel's device time by that name."""
+    hlo = _compile(lambda q, k, v, pt, nv: paged_decode_attention_pallas(
+                       q, k, v, pt, nv, interpret=False), one_chip,
+                   *_paged_shapes(*PAGED["qwen3-8b"]))
+    names = re.findall(r"%(\S+) = \S+ custom-call\(.*"
+                       r'custom_call_target="tpu_custom_call"', hlo)
+    assert names and all("paged_decode_attention" in n for n in names)
 
 
 @pytest.mark.parametrize("n", [1, 1024])

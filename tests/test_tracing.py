@@ -93,6 +93,33 @@ def test_decode_page_counters_are_exact(pool):
     uniform.drain()
     c = uniform.counters()
     assert c["decode_pages_valid"] == c["decode_pages_mapped"] == 0
+    assert c["decode_pages_read"] == 0
+
+
+def test_decode_pages_read_counts_whole_blocks(pool, monkeypatch):
+    """With blocks of 2 pages (a 256-byte block target over TINY's 128-byte
+    bf16 pages), each chunk counts every slot's pages at its end rounded up to
+    whole blocks, at most the 8-page table: the idle slot, at pos 0, still
+    costs one block. The count lies between the valid and mapped pages."""
+    from repro.kernels import decode_attention
+    monkeypatch.setattr(decode_attention, "PAGED_BLOCK_BYTES", 256)
+    e = ServeEngine(pool, PAGED)
+    e.submit(0, _prompt(5), 6)
+    e.submit(0, _prompt(3), 4)
+    assert e._lanes[0].block_pages == 2
+    e.step()        # 3 and 2 pages at the chunk's end: blocks of 4 and 2
+    c = e.counters()
+    assert (c["decode_pages_valid"], c["decode_pages_read"],
+            c["decode_pages_mapped"]) == (5, 6, 16)
+    e.step()        # 4 pages, and the idle slot's 1: blocks of 4 and 2
+    c = e.counters()
+    assert (c["decode_pages_valid"], c["decode_pages_read"],
+            c["decode_pages_mapped"]) == (9, 12, 32)
+    long = ServeEngine(pool, EngineConfig(slots=1, max_seq=20, chunk=4,
+                                          page_size=4))
+    long.submit(0, _prompt(13), 4)
+    long.step()     # 5 pages of a 5-page table: blocks of 6, capped at 5
+    assert long.counters()["decode_pages_read"] == 5
 
 
 @pytest.mark.parametrize("page_size", [4, None], ids=["paged", "uniform"])
