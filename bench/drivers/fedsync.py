@@ -75,9 +75,11 @@ def run(ctx) -> dict:
                             num_models=len(costs))
         state0 = weights.router_state(rcfg, quality, costs, seed,
                                       r.get("head_scale", 1.0))
-        pool = [PoolModel(f"lane{i}", weights.model_config(
-            cfg if lane["model"] == "self" else cfg[lane["model"]]), None,
-            lane["cost_per_token"]) for i, lane in enumerate(cfg["pool"])]
+        models = [cfg if lane["model"] == "self" else cfg[lane["model"]]
+                  for lane in cfg["pool"]]
+        pool = [PoolModel(f"lane{i}", ctx.family(m).model_config(m), None,
+                          lane["cost_per_token"])
+                for i, (m, lane) in enumerate(zip(models, cfg["pool"]))]
         srv = RoutedServer(pool, routers.make(r["family"], rcfg,
                                               num_models=len(costs),
                                               state=state0),

@@ -23,7 +23,7 @@ import time
 
 import numpy as np
 
-from bench import reference, traffic, yardstick
+from bench import reference, traffic
 
 
 def _next_pow2(v: int) -> int:
@@ -48,6 +48,7 @@ class Serving:
         self.records = {}          # rid -> dict
         self.steps = []            # per window step: dict
         self.submit_s = []         # host seconds per RoutedServer.submit
+        self.counters = collections.Counter()  # engine counters: window deltas
 
     # ------------------------------------------------------------- set-up
     def build(self):
@@ -60,13 +61,15 @@ class Serving:
         from bench import weights
 
         cfg, ctx = self.cfg, self.ctx
-        self.models, self.params, pool = [], [], []
+        self.models, self.families, self.params, pool = [], [], [], []
         with ctx.phase("weights"):
             for lane in cfg["pool"]:
                 m = cfg if lane["model"] == "self" else cfg[lane["model"]]
+                fam = ctx.family(m)
                 mcfg, params = weights.model_params(
-                    m, self.seed + lane["seed_offset"])
+                    fam, m, self.seed + lane["seed_offset"])
                 self.models.append(m)
+                self.families.append(fam)
                 self.params.append(params)
                 pool.append(PoolModel(f"{m['architectures'][0]}"
                                       f"/{lane['seed_offset']}", mcfg,
@@ -161,10 +164,14 @@ class Serving:
         """One engine step; returns the requests it finished."""
         chunk = self.ecfg.chunk
         before = [rid for act in self.lanes.active for rid in act]
+        c0 = self.srv.engine.counters() if in_window else None
         t0 = time.perf_counter()
         with self.ctx.span("bench.step"):
             finished = self.srv.step()
         t1 = time.perf_counter()
+        if in_window:
+            for k, v in self.srv.engine.counters().items():
+                self.counters[k] += v - c0.get(k, 0)
         admitted = []
         for lane, q in enumerate(self.lanes.queue):
             while q and self.srv.status(q[0]) != "QUEUED":
@@ -314,35 +321,41 @@ class Serving:
 
     def layer_facts(self) -> dict:
         """Counts and host timings of the window for the per-layer
-        readers: step times, occupancy, FLOPs of the work done, and the
-        paged attention cost of every decode step."""
-        steps, ecfg = self.steps, self.ecfg
+        readers: step times, occupancy, FLOPs of the work done, each
+        kernel's FLOPs and bytes over every decode step, as each lane's
+        model family counts them, and the engine's counter deltas."""
+        from bench.readers import PAGED_ATTN
+        steps, ecfg, fams = self.steps, self.ecfg, self.families
         slots_total = ecfg.slots * len(self.models)
-        flops, attn = 0.0, []
+        flops = 0.0
         for s in steps:
             for lane, ctxs in enumerate(s["contexts"]):
                 m = self.models[lane]
-                flops += sum(yardstick.token_flops(m, c) for c in ctxs)
+                flops += sum(fams[lane].token_flops(m, c) for c in ctxs)
             for lane, n in s["admitted"]:
-                flops += yardstick.prefill_flops(self.models[lane], n)
+                flops += fams[lane].prefill_flops(self.models[lane], n)
         # per decode step of a chunk, each lane's attention call reads the
         # rows that are still inside their requests
+        cost = collections.defaultdict(lambda: [0, 0])
         for s in steps:
             for lane, ctxs in enumerate(s["contexts"]):
-                m = self.models[lane]
+                m, fam = self.models[lane], fams[lane]
                 if not ctxs:
                     continue
-                f, b = yardstick.paged_attn_call_cost(m, ctxs)
-                attn.append((f * m["num_hidden_layers"],
-                             b * m["num_hidden_layers"]))
+                each = {PAGED_ATTN: fam.decode_attn_cost(m, ctxs)}
+                if hasattr(fam, "kernel_costs"):
+                    each.update(fam.kernel_costs(m, ctxs))
+                for name, (f, b) in each.items():
+                    cost[name][0] += f
+                    cost[name][1] += b
         span = steps[-1]["t1"] - steps[0]["t0"] if steps else 0.0
         return {
             "step_s": [s["t1"] - s["t0"] for s in steps],
             "occupancy": [s["rows"] / slots_total for s in steps],
             "submit_s": list(self.submit_s),
             "model_flops": flops,
-            "paged_attn_flops": sum(a[0] for a in attn),
-            "paged_attn_bytes": sum(a[1] for a in attn),
+            "kernel_cost": {k: tuple(v) for k, v in cost.items()},
+            "counters": dict(self.counters),
             "window_span_s": span,
         }
 
@@ -388,7 +401,7 @@ class Serving:
                                      self.seed >> 32, 13])
         k = int(self.mix["check"]["per_lane"])
         tok_lim = lim.get("tok_gap")
-        for lane, m in enumerate(self.models):
+        for lane, (m, fam) in enumerate(zip(self.models, self.families)):
             mine = [r for r in done if r["lane"] == lane]
             if not mine:
                 continue
@@ -399,15 +412,15 @@ class Serving:
                 len(rest), size=min(k - 1, len(rest)), replace=False))]
             worst, n_tok, ctl = 0.0, 0, 0.0
             for r in pick:
-                gaps = reference.token_gaps(self.params[lane], m,
-                                            r["req"].prompt, r["tokens"],
-                                            length=self.ecfg.max_seq)
+                gaps = fam.token_gaps(self.params[lane], m,
+                                      r["req"].prompt, r["tokens"],
+                                      length=self.ecfg.max_seq)
                 n_tok += len(gaps)
                 worst = max(worst, float(gaps.max()))
                 if tok_lim is not None and gaps.max() > tok_lim[lane]:
                     out["rejected"].add(r["req"].index)
                 if control:
-                    ctl = max(ctl, float(reference.token_gaps(
+                    ctl = max(ctl, float(fam.token_gaps(
                         self.params[lane], m, r["req"].prompt, r["tokens"],
                         length=self.ecfg.max_seq, control=True).max()))
             name = f"tok_gap.lane{lane}"

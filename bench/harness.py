@@ -8,7 +8,17 @@ of its own, found by name:
 - ``bench/configs/<config>.json``  the configuration as it is run;
 - ``bench/traffic/<traffic>.json``  the mix's parameters; its ``driver``
   key names the driver (``bench/drivers/``) that runs it;
-- ``bench/metrics/<metric>.py``  a reader with ``read(run) -> float|None``.
+- ``bench/metrics/<metric>.py``  a reader with ``read(run) -> float|None``;
+- ``bench/models/<architectures[0]>.py``  a served model's family, keyed by
+  the Hugging Face class name its configuration gives: the program's
+  ``ModelConfig`` (``model_config(m)``), optionally ``leaf(key, names,
+  shape, dtype, m)`` for leaves ``bench.weights`` does not draw itself, the
+  float32 reference (``token_gaps(params, m, prompt, served, *, length,
+  control)``) and the yardstick (``token_flops(m, context)``,
+  ``prefill_flops(m, n)``, ``decode_attn_cost(m, contexts)``: the paged
+  decode attention of one decode step over every layer, and optionally
+  ``kernel_costs(m, contexts)``: ``{kernel: (flops, bytes)}`` a decode
+  step).
 
 A cell, a configuration or a metric is added by adding such files and
 entries in ``BENCHMARK.json``; nothing here names one.
@@ -16,6 +26,7 @@ entries in ``BENCHMARK.json``; nothing here names one.
 from __future__ import annotations
 
 import contextlib
+import functools
 import importlib
 import importlib.util
 import json
@@ -81,18 +92,41 @@ def resolve(spec: dict, workload: str, root=ROOT) -> dict:
     for name, path in readers.items():
         if not path.is_file():
             raise SpecError(f"metric {name}: reader {path} is missing")
+    for lane in config.get("pool", []):
+        family_path(config if lane["model"] == "self"
+                    else config[lane["model"]], root)
     return {"cell": cell, "config": config, "traffic": mix, "e2e": e2e,
             "per_layer": layer, "readers": readers,
             "driver": DRIVER_MODULES[mix["driver"]]}
 
 
-def load_reader(path: pathlib.Path):
+@functools.lru_cache(maxsize=None)
+def _load(path: pathlib.Path, prefix: str):
+    """The module in the file at ``path``, loaded once."""
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + path.stem.replace(".", "_").replace("-", "_"),
-        path)
+        prefix + path.stem.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(path: pathlib.Path):
+    return _load(path, "bench_metric_").read
+
+
+def family_path(m: dict, root=ROOT) -> pathlib.Path:
+    """The file of model ``m``'s family: ``bench/models/<its
+    architectures[0]>.py``."""
+    path = (pathlib.Path(root) / "bench" / "models"
+            / f"{m['architectures'][0]}.py")
+    if not path.is_file():
+        raise SpecError(f"model family {path} is missing")
+    return path
+
+
+def load_family(m: dict, root=ROOT):
+    """Model ``m``'s family module (see the module docstring)."""
+    return _load(family_path(m, root), "bench_family_")
 
 
 # ---------------------------------------------------------------- context
@@ -114,8 +148,9 @@ class Context:
     """What a driver sees of the harness."""
 
     def __init__(self, *, config, traffic, seed, seconds, trace, control,
-                 t_start, log=None):
+                 t_start, log=None, root=ROOT):
         self.config, self.traffic = config, traffic
+        self.root = root
         self.seed, self.seconds = seed, float(seconds)
         self.trace, self.control = bool(trace), bool(control)
         self.t_start = t_start
@@ -139,6 +174,10 @@ class Context:
 
     def log(self, msg: str):
         self._log(msg)
+
+    def family(self, m: dict):
+        """Model ``m``'s family module, found under this checkout."""
+        return load_family(m, self.root)
 
     @contextlib.contextmanager
     def phase(self, name: str):
@@ -218,9 +257,11 @@ def enable_cache():
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
              root=ROOT, t_start=None, require_chip=True, control=False,
-             log=None) -> dict:
+             log=None, keep=None) -> dict:
     """One run of one cell. Returns the result object (without printing)
-    and the compared numbers under ``checks``."""
+    and the compared numbers under ``checks``. Where ``keep`` is a dict,
+    the driver's end-to-end numbers go in it under ``e2e`` and, in a traced
+    run, what the readers read under ``run``."""
     t_start = time.perf_counter() if t_start is None else t_start
     spec = load_spec(root)
     r = resolve(spec, workload, root)
@@ -229,7 +270,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         sys.path.insert(0, str(src))
     ctx = Context(config=r["config"], traffic=r["traffic"], seed=seed,
                   seconds=seconds, trace=trace, control=control,
-                  t_start=t_start, log=log)
+                  t_start=t_start, log=log, root=pathlib.Path(root))
     with ctx.phase("import and jax start"):
         try:
             importlib.import_module("repro.serve.gateway")
@@ -272,6 +313,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         run = Run(facts=out["facts"], trace=red,
                   peaks=yardstick.peaks(dev["kind"]) if require_chip
                   else None)
+        if keep is not None:
+            keep["run"] = run
         for m in r["per_layer"]:
             v = load_reader(r["readers"][m["name"]])(run)
             if v is not None:
@@ -289,6 +332,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             if v is not None:
                 result["metrics"][m["name"]] = {"value": float(v),
                                                 "unit": m["unit"]}
+    if keep is not None:
+        keep["e2e"] = out["e2e"]
     if control:
         result["control"] = out.get("control", {})
     result["checks"] = checks

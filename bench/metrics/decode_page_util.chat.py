@@ -1,0 +1,6 @@
+"""decode_page_util.chat: see ``bench.spans.decode_page_util``."""
+from bench import spans
+
+
+def read(run):
+    return spans.decode_page_util(run)

@@ -46,18 +46,24 @@ def mfu(run):
     return 100.0 * f / (s * run.peaks["flops_bf16"])
 
 
-def paged_attn_roofline(run):
-    """The least time the kernel's useful work needs (K/V of the valid
-    positions, queries and outputs at the HBM bandwidth, or its FLOPs at
-    the peak, whichever is longer) over its summed device time, per
-    cent."""
+def roofline(run, name):
+    """The least time kernel ``name``'s useful work needs (its bytes at
+    the HBM bandwidth or its FLOPs at the peak, whichever is longer, as
+    its model family counts them in ``facts["kernel_cost"]``) over its
+    summed device time, per cent."""
     t = run.trace
     if not t or run.peaks is None:
         return None
-    k = tr.kernel_seconds(t, PAGED_ATTN)
-    b = run.facts.get("paged_attn_bytes", 0.0)
+    k = tr.kernel_seconds(t, name)
+    flops, b = run.facts.get("kernel_cost", {}).get(name, (0.0, 0.0))
     if k <= 0 or not b:
         return None
     least = max(b / run.peaks["hbm_bytes_per_s"],
-                run.facts["paged_attn_flops"] / run.peaks["flops_bf16"])
+                flops / run.peaks["flops_bf16"])
     return 100.0 * least / k
+
+
+def paged_attn_roofline(run):
+    """The paged decode attention kernel's roofline share: K/V of the
+    valid positions, queries and outputs."""
+    return roofline(run, PAGED_ATTN)

@@ -1,7 +1,8 @@
-"""CPU tests of the benchmark: lookups by name, the traffic generator, the
-trace reduction, the refusal to run without a chip, and the comparison
-that decides ``correct`` (with its control and a planted fault) on a tiny
-copy of the mixed-pool cell."""
+"""CPU tests of the benchmark: lookups by name (a model family among
+them), the traffic generator, the trace reduction, the Qwen family's
+numbers against the parent's, the refusal to run without a chip, and the
+comparison that decides ``correct`` (with its control and a planted fault)
+on a tiny copy of the mixed-pool cell."""
 from __future__ import annotations
 
 import collections
@@ -50,7 +51,7 @@ def test_every_cell_resolves_by_name(workload):
 
 def _copy_tree(dst: pathlib.Path):
     shutil.copy(ROOT / "BENCHMARK.json", dst / "BENCHMARK.json")
-    for sub in ("configs", "traffic", "metrics"):
+    for sub in ("configs", "traffic", "metrics", "models"):
         shutil.copytree(ROOT / "bench" / sub, dst / "bench" / sub)
 
 
@@ -89,6 +90,20 @@ def test_new_cell_is_found_from_new_files_alone(tmp_path):
     assert [m["name"] for m in r["per_layer"]] == ["new_metric.new_cell"]
     assert harness.load_reader(r["readers"]["new_metric.new_cell"])(
         None) == 42.0
+
+
+def test_a_missing_family_is_a_spec_error(tmp_path):
+    """A served model's family is the file its architecture names; where
+    that file is missing, the cell does not resolve, and the error names
+    the path."""
+    _copy_tree(tmp_path)
+    conf = tmp_path / "bench" / "configs" / "qwen2-1.5b-x2.json"
+    c = json.loads(conf.read_text())
+    c["architectures"] = ["NoSuchForCausalLM"]
+    conf.write_text(json.dumps(c))
+    with pytest.raises(harness.SpecError, match="NoSuchForCausalLM.py"):
+        harness.resolve(harness.load_spec(tmp_path), "chat_qwen2x2",
+                        tmp_path)
 
 
 # ---------------------------------------------------------------- traffic
@@ -164,8 +179,8 @@ def test_trace_reduction_arithmetic():
         ("paged_decode_attention_pallas.1", 5 * ms, 1 * ms),
         ("fusion.2", 9 * ms, 2 * ms),                # clipped at 10 ms
     ]}
-    spans = [("bench.window", 0, 10 * ms), ("bench.step", 0, 8 * ms),
-             ("bench.refill", 8 * ms, 2 * ms)]
+    spans = [("bench.window", 0, 10 * ms, {}), ("bench.step", 0, 8 * ms, {}),
+             ("bench.refill", 8 * ms, 2 * ms, {})]
     red = trace.reduce_events(ops, spans, (0, 10 * ms))
     assert red["window_s"] == pytest.approx(0.010)
     assert red["busy_s"] == pytest.approx(0.007)        # 0-2, 3-7, 9-10
@@ -182,12 +197,18 @@ def test_trace_reduction_arithmetic():
                                         "jit_prefill/fusion.1"]
     # roofline: 1.638 MB in 2 ms at 819 GB/s is 1% of the kernel's time
     peaks = {"hbm_bytes_per_s": 819e9, "flops_bf16": 197e12}
-    run = _Run({"paged_attn_bytes": 819e9 * 2e-5, "paged_attn_flops": 1.0},
+    run = _Run({"kernel_cost": {"paged_decode_attention": (1.0,
+                                                           819e9 * 2e-5)}},
                red, peaks)
     assert readers.paged_attn_roofline(run) == pytest.approx(1.0)
     # a compute-bound call: the FLOPs set the least time
-    run.facts = {"paged_attn_bytes": 1.0, "paged_attn_flops": 197e12 * 1e-3}
+    run.facts = {"kernel_cost": {"paged_decode_attention": (197e12 * 1e-3,
+                                                           1.0)}}
     assert readers.paged_attn_roofline(run) == pytest.approx(50.0)
+    # any kernel a family costs, by the same arithmetic
+    run.facts = {"kernel_cost": {"fusion.2": (1.0, 819e9 * 1e-5)}}
+    assert readers.roofline(run, "fusion.2") == pytest.approx(1.0)
+    assert readers.roofline(run, "paged_decode_attention") is None
     run = _Run({"model_flops": 197e12 * 0.5, "window_span_s": 2.0}, red,
                peaks)
     assert readers.mfu(run) == pytest.approx(25.0)
@@ -209,6 +230,85 @@ def test_recorded_trace_finds_its_window(tmp_path):
     assert red["window_s"] > 0
     assert red["busy_s"] == 0.0          # the CPU has no TPU plane
     assert readers.idle_share(_Run({}, red, None)) == pytest.approx(100.0)
+
+
+# ------------------------------------------- the Qwen family, as it was
+
+#: the parent's numbers (its ``bench.yardstick``): token_flops(m, 777),
+#: prefill_flops(m, 333), and the paged attention's FLOPs and bytes of one
+#: decode step at contexts (17, 300, 1024) times num_hidden_layers
+YARDSTICK_PINS = {
+    "qwen3-8b_qwen2-1.5b": (8419573760.0, 2330585563136.0, 395476992.0,
+                            99753984),
+    "qwen3-8b_qwen2-1.5b/second_lane": (3220807680.0, 882623963136.0,
+                                        230694912.0, 38965248),
+    "qwen2-1.5b-x2": (3220807680.0, 882623963136.0, 230694912.0, 38965248),
+}
+
+
+def _published(name):
+    conf, _, sub = name.partition("/")
+    m = json.loads((ROOT / "bench" / "configs" / f"{conf}.json").read_text())
+    return m[sub] if sub else m
+
+
+@pytest.mark.parametrize("name", sorted(YARDSTICK_PINS))
+def test_qwen_family_costs_match_the_parents_yardstick(name):
+    m = _published(name)
+    fam = harness.load_family(m)
+    tok, pre, f, b = YARDSTICK_PINS[name]
+    assert fam.token_flops(m, 777) == tok
+    assert fam.prefill_flops(m, 333) == pre
+    assert fam.decode_attn_cost(m, [17, 300, 1024]) == (f, b)
+    assert not hasattr(fam, "kernel_costs")
+
+
+#: the parent's readings on the tiny mixed-pool models from SEED: the
+#: float64 sum and absolute sum of every weight, and the reference's and
+#: the control's gaps of fixed served tokens after a fixed prompt
+TINY_PINS = {
+    "Qwen3ForCausalLM": (
+        1513.8900968381204, 65183.79068684345,
+        [4.732832908630371, 2.4549460411071777, 3.300588369369507,
+         3.4944705963134766, 2.7612075805664062, 3.0194129943847656,
+         3.0277621746063232, 3.450672149658203, 1.950348138809204,
+         4.583173751831055, 2.4446139335632324, 3.171985626220703],
+        [0.0, 0.0, 0.13392138481140137, 0.0, 0.0, 0.15558218955993652,
+         0.1273331642150879, 0.0, 0.0, 0.0, 0.0, 0.4947376251220703]),
+    "Qwen2ForCausalLM": (
+        1313.4532025186345, 60740.35983352084,
+        [1.3161770105361938, 1.1600381135940552, 1.2983677387237549,
+         0.32275688648223877, 0.6960111856460571, 0.16045546531677246,
+         1.2482489347457886, 0.9573291540145874, 0.5515042543411255,
+         1.049033522605896, 1.1925886869430542, 0.9186522960662842],
+        [0.0, 0.0, 0.0, 0.0, 0.0, 0.0035493969917297363, 0.0, 0.0, 0.0,
+         0.0, 0.0, 0.03616952896118164]),
+}
+
+
+@pytest.mark.parametrize("arch", sorted(TINY_PINS))
+def test_qwen_family_weights_and_gaps_match_the_parent(arch, tmp_path):
+    import jax
+    from bench import weights
+    conf = json.loads((_tiny_root(tmp_path) / "bench" / "configs"
+                       / "tiny.json").read_text())
+    m = next(x for x in (conf, conf["second_lane"])
+             if x["architectures"][0] == arch)
+    fam = harness.load_family(m)
+    cfg, params = weights.model_params(fam, m, SEED)
+    assert cfg.name == f"{arch}-2L-256"
+    leaves = [np.asarray(a, np.float64) for a in jax.tree.leaves(params)]
+    total, absolute, gaps, control = TINY_PINS[arch]
+    assert sum(float(a.sum()) for a in leaves) == pytest.approx(total,
+                                                                rel=1e-9)
+    assert sum(float(np.abs(a).sum()) for a in leaves) == pytest.approx(
+        absolute, rel=1e-9)
+    prompt = (np.arange(24) * 37 + 5) % 511 + 1
+    served = (np.arange(12) * 91 + 3) % 512
+    g = fam.token_gaps(params, m, prompt, served, length=128)
+    c = fam.token_gaps(params, m, prompt, served, length=128, control=True)
+    assert g == pytest.approx(gaps, abs=1e-5)
+    assert c == pytest.approx(control, abs=1e-5)
 
 
 # ------------------------------------------------------ no chip, no result
@@ -247,7 +347,8 @@ def _tiny_root(root: pathlib.Path) -> pathlib.Path:
     key but the widths and depth as the chip runs it) under both mixes."""
     (root / "bench" / "configs").mkdir(parents=True)
     (root / "bench" / "traffic").mkdir()
-    shutil.copytree(ROOT / "bench" / "metrics", root / "bench" / "metrics")
+    for sub in ("metrics", "models"):
+        shutil.copytree(ROOT / "bench" / sub, root / "bench" / sub)
     (root / "src").symlink_to(ROOT / "src")
     conf = json.loads((ROOT / "bench" / "configs"
                        / "qwen3-8b_qwen2-1.5b.json").read_text())
@@ -311,6 +412,86 @@ def tiny(tmp_path_factory):
 def _run(tiny, workload, **kw):
     return harness.run_cell(workload, SEED, 4.0, False, root=tiny,
                             require_chip=False, log=lambda s: None, **kw)
+
+
+#: a model family that no file of the benchmark names: the Qwen decoder
+#: under a marked ModelConfig name, counting the calls the harness makes
+MARKED_FAMILY = """
+import dataclasses
+
+from bench.models import _qwen
+
+CALLS = {}
+
+
+def _count(name):
+    CALLS[name] = CALLS.get(name, 0) + 1
+
+
+def model_config(m):
+    _count("model_config")
+    return dataclasses.replace(_qwen.model_config(m), name="marked-family")
+
+
+def leaf(key, names, shape, dtype, m):
+    _count("leaf")
+    return None
+
+
+def token_gaps(*a, **kw):
+    _count("token_gaps")
+    return _qwen.token_gaps(*a, **kw)
+
+
+def token_flops(m, context):
+    _count("token_flops")
+    return _qwen.token_flops(m, context)
+
+
+def prefill_flops(m, n):
+    _count("prefill_flops")
+    return _qwen.prefill_flops(m, n)
+
+
+def decode_attn_cost(m, contexts):
+    _count("decode_attn_cost")
+    return _qwen.decode_attn_cost(m, contexts)
+
+
+def kernel_costs(m, contexts):
+    return {"marked_kernel": (1.0, float(len(contexts)))}
+"""
+
+
+def test_new_family_is_served_checked_and_costed_through_its_file(tmp_path):
+    """A configuration whose architecture names a family file that only a
+    new file holds is built, served, compared with its reference and
+    costed through that file, with no other file of the benchmark
+    edited."""
+    root = _tiny_root(tmp_path)
+    (root / "bench" / "models" / "MarkedForCausalLM.py").write_text(
+        MARKED_FAMILY)
+    conf = root / "bench" / "configs" / "tiny.json"
+    c = json.loads(conf.read_text())
+    c["architectures"] = ["MarkedForCausalLM"]
+    c["limits"] = _limits()
+    conf.write_text(json.dumps(c))
+    keep = {}
+    res = harness.run_cell("tiny_saturate", SEED, 3.0, True, root=root,
+                           require_chip=False, log=lambda s: None,
+                           keep=keep)
+    assert res["correct"], res["checks"]
+    fam = harness.load_family(c, root)
+    assert fam.__file__ == str(root / "bench" / "models"
+                               / "MarkedForCausalLM.py")
+    for name in ("model_config", "leaf", "token_gaps", "token_flops",
+                 "prefill_flops", "decode_attn_cost"):
+        assert fam.CALLS.get(name, 0) > 0, name
+    from bench import weights
+    assert weights.model_params(fam, c, SEED)[0].name == "marked-family"
+    cost = keep["run"].facts["kernel_cost"]
+    assert set(cost) == {"paged_decode_attention", "marked_kernel"}
+    assert cost["marked_kernel"][1] > 0
 
 
 def test_closed_loop_counts_only_errors_and_rejections(tiny):

@@ -1,12 +1,13 @@
-"""CPU tests of ``bench.spans``: the reduction of the program's spans and
-programs on synthetic events, its readers on hand-made numbers, and traced
-tiny runs of a served and a sync cell whose span counts agree exactly with
-the steps, submits and syncs the loops count."""
+"""CPU tests of the program's spans, programs and counters as the
+harness reads them: ``bench.trace``'s reduction of them on synthetic
+events, ``bench.spans``' readers on hand-made numbers, and traced tiny
+runs of a served and a sync cell through ``harness.run_cell`` whose span
+counts agree exactly with the steps, submits and syncs the loops count."""
 from __future__ import annotations
 
 import pytest
 
-from bench import spans, trace
+from bench import harness, spans, trace
 from bench.test_bench import SEED, _tiny_root
 
 MS = 1_000_000
@@ -40,9 +41,13 @@ def _events():
     return ops, modules, host, (0, 10 * MS)
 
 
+def _reduce(ops, modules, host, window):
+    return trace.reduce_events(ops, host, window, modules)
+
+
 def test_program_spans_and_modules_are_clipped_to_the_window():
     ops, modules, host, window = _events()
-    red = spans.extra(ops, modules, host, window)
+    red = _reduce(ops, modules, host, window)
     assert [(n, s) for n, s, _, _ in red["spans"]] == [
         ("engine.step", pytest.approx(0.0005)),
         ("engine.prefill", pytest.approx(0.001)),
@@ -56,35 +61,44 @@ def test_program_spans_and_modules_are_clipped_to_the_window():
         "jit_engine_decode_paged(7)": pytest.approx(0.004),
         "jit_engine_write_pages(5)": pytest.approx(0.0005),
         "jit_fedavg_fit(1)": pytest.approx(0.001)}
-    # the harness's reduction is left as it was: nothing is overwritten
-    base = trace.reduce_events(ops, [h[:3] for h in host], window)
-    assert not set(red) & set(base)
+    # busy time, operations and gap lengths are as the harness's
+    # reduction of its own spans alone gives them
+    base = trace.reduce_events(ops, [h for h in host
+                                     if h[0].startswith("bench.")], window)
+    for k in ("busy_s", "window_s", "op_s", "device_ops"):
+        assert red[k] == base[k], k
+    assert [g[1] for g in red["idle_gaps"]] == [g[1] for g in
+                                                base["idle_gaps"]]
+    assert base["spans"] == [] and base["module_s"] == {}
 
 
 def test_gaps_are_named_by_the_innermost_span_of_either_kind():
-    red = spans.extra(*_events())
-    assert red["named_gaps"] == [
+    red = _reduce(*_events())
+    assert red["idle_gaps"] == [
         ["bench.step", pytest.approx(0.002)],       # 7-9 ms: no program span
         ["engine.step", pytest.approx(0.001)],      # 2-3 ms: the step's own
         ["engine.decode", pytest.approx(0.0005)]]   # 5-5.5 ms
 
 
 def test_self_time_counts_nested_children_once():
-    assert spans.self_time((0, 10), [(2, 4), (3, 5), (9, 12), (-1, 1),
+    assert trace.self_time((0, 10), [(2, 4), (3, 5), (9, 12), (-1, 1),
                                      (20, 30)]) == 5
-    assert spans.self_time((0, 10), []) == 10
+    assert trace.self_time((0, 10), []) == 10
 
 
 def test_readers_on_hand_made_numbers():
     run = _Run({"step_s": [0.5, 0.5], "syncs": 2,
-                "decode_pages_valid": 9, "decode_pages_mapped": 32},
-               spans.extra(*_events()))
+                "counters": {"decode_pages_valid": 9,
+                             "decode_pages_mapped": 32,
+                             "decode_pages_read": 12}},
+               _reduce(*_events()))
     assert spans.route_ms(run) == pytest.approx(1.0)
     # 7 ms step less 1.2 ms of prefill and 4.2 ms of decode
     assert spans.step_host_ms(run) == pytest.approx(1.6)
     assert spans.prefill_ms(run) == pytest.approx(1.25)    # 2.5 ms / 2
     assert spans.fit_ms(run) == pytest.approx(0.5)         # 1 ms / 2
     assert spans.decode_page_util(run) == pytest.approx(28.125)
+    assert spans.decode_pages_read_share(run) == pytest.approx(37.5)
     assert spans.inside_share(run, spans.DECODE_MODULE,
                               "engine.decode") == pytest.approx(100.0)
     assert spans.inside_share(run, spans.FIT_MODULE,
@@ -118,13 +132,14 @@ def tiny(tmp_path_factory):
 
 
 def _traced(tiny, workload):
-    logs = []
-    res, run, e2e = spans.run_cell(workload, SEED, 3.0, root=tiny,
-                                   require_chip=False, log=logs.append)
+    logs, keep = [], {}
+    res = harness.run_cell(workload, SEED, 3.0, True, root=tiny,
+                           require_chip=False, log=logs.append, keep=keep)
+    run = keep["run"]
     counts = {}
     for sp in run.trace["spans"]:
         counts[sp[0]] = counts.get(sp[0], 0) + 1
-    return res, run, e2e, counts, logs
+    return res, run, keep["e2e"], counts, logs
 
 
 def test_traced_chat_counts_one_span_per_step_and_submit(tiny):
@@ -135,16 +150,38 @@ def test_traced_chat_counts_one_span_per_step_and_submit(tiny):
     assert counts["engine.decode"] >= steps
     assert "programs traced after warm-up: 0 []" in logs
     assert set(e2e) == {"ttft_p95_ms", "tpot_p95_ms"}
-    for name in ("route_ms", "step_host_ms", "decode_page_util"):
+    for name in ("route_ms", "step_host_ms", "decode_page_util",
+                 "decode_pages_read_share"):
         v = spans.SERVED[name](run)
         assert v is not None and v > 0, name
     assert 0 < spans.decode_page_util(run) <= 100
     assert spans.prefill_ms(run) is None       # no device line off the chip
-    assert set(res["metrics"]) >= {"step_ms.chat", "submit_ms.chat"}
+    assert set(res["metrics"]) >= {"step_ms.chat", "submit_ms.chat",
+                                   "route_ms.chat", "decode_page_util.chat",
+                                   "decode_pages_read_share.chat"}
+    assert "prefill_ms.chat" not in res["metrics"]
+
+
+def test_traced_saturate_carries_every_counter_delta(tiny):
+    """Every counter of ``ServeEngine.counters()`` reaches the readers as
+    its window delta, so that a counter the program adds needs no edit."""
+    from repro.serve.engine import EngineConfig, ServeEngine
+    res, run, _, counts, _ = _traced(tiny, "tiny_saturate")
+    c = run.facts["counters"]
+    names = ServeEngine([], EngineConfig()).counters()
+    assert set(c) == set(names)
+    assert 0 < c["decode_pages_valid"] <= c["decode_pages_read"] \
+        <= c["decode_pages_mapped"]
+    assert c["sheds"] == c["expiries"] == 0
+    assert counts["engine.step"] == len(run.facts["step_s"])
+    assert set(res["metrics"]) >= {"step_host_ms.saturate",
+                                   "decode_page_util.saturate",
+                                   "decode_pages_read_share.saturate",
+                                   "occupancy.saturate", "step_ms.saturate"}
 
 
 def test_traced_sync_counts_one_of_each_span_per_sync(tiny):
-    _, run, e2e, counts, _ = _traced(tiny, "tiny_fedsync")
+    res, run, e2e, counts, _ = _traced(tiny, "tiny_fedsync")
     syncs = run.facts["syncs"]
     assert syncs > 0
     assert counts == {"fed.sync": syncs, "fed.stack": syncs,
@@ -152,3 +189,5 @@ def test_traced_sync_counts_one_of_each_span_per_sync(tiny):
     assert spans.stack_ms(run) > 0
     assert spans.fit_ms(run) is None
     assert set(e2e) == {"sync_ms"}
+    assert "stack_ms.fedsync" in res["metrics"]
+    assert "fit_ms.fedsync" not in res["metrics"]

@@ -2,13 +2,16 @@
 in: one jitted call per model and one for the router.
 
 The program supplies only the layout (``jax.eval_shape`` of its own
-initialiser gives the tree of shapes); every value is drawn here. Norm
-scales are drawn around 1 and the QKV biases away from 0, so that a path
-that skipped them would not agree with the reference.
+initialiser, for the ``ModelConfig`` the model's family gives, gives the
+tree of shapes); every value is drawn here, by ``_model_leaf`` or, for the
+leaves a family draws itself, by its ``leaf``. Norm scales are drawn
+around 1 and the QKV biases away from 0, so that a path that skipped them
+would not agree with the reference.
 """
 from __future__ import annotations
 
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
@@ -16,21 +19,6 @@ import numpy as np
 
 #: leaves larger than this are drawn in slices (f32 draws, then a cast)
 _SLICE_ELEMS = 1 << 25
-
-
-def model_config(m: dict):
-    """The program's ModelConfig for a model's config keys."""
-    from repro.config import ModelConfig
-    return ModelConfig(
-        name=f"{m['architectures'][0]}-{m['num_hidden_layers']}L"
-             f"-{m['hidden_size']}",
-        arch_type="dense", n_layers=m["num_hidden_layers"],
-        d_model=m["hidden_size"], n_heads=m["num_attention_heads"],
-        n_kv_heads=m["num_key_value_heads"], d_ff=m["intermediate_size"],
-        vocab=m["vocab_size"], head_dim=m["head_dim"],
-        qk_norm=m["qk_norm"], qkv_bias=m["attention_bias"],
-        rope_theta=m["rope_theta"], norm_eps=m["rms_norm_eps"],
-        dtype=m["torch_dtype"], tie_embeddings=False)
 
 
 def _names(path) -> list:
@@ -69,10 +57,11 @@ def _model_leaf(key, names, shape, dtype, m):
 
 
 @functools.lru_cache(maxsize=None)
-def _model_maker(m_items):
+def _model_maker(fam, m_json):
     from repro.models import init_params
-    m = dict(m_items)
-    cfg = model_config(m)
+    m = json.loads(m_json)
+    cfg = fam.model_config(m)
+    own = getattr(fam, "leaf", None)
     shapes = jax.eval_shape(lambda k: init_params(k, cfg),
                             jax.random.PRNGKey(0))
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
@@ -85,8 +74,10 @@ def _model_maker(m_items):
             if tied and names[-1] == "unembed":
                 leaves.append(None)
                 continue
-            a = _model_leaf(jax.random.fold_in(key, i), names, s.shape,
-                            s.dtype, m)
+            k = jax.random.fold_in(key, i)
+            a = own(k, names, s.shape, s.dtype, m) if own else None
+            if a is None:
+                a = _model_leaf(k, names, s.shape, s.dtype, m)
             by_name[tuple(names)] = a
             leaves.append(a)
         if tied:   # the output head is the token table, transposed
@@ -97,23 +88,10 @@ def _model_maker(m_items):
     return cfg, jax.jit(make)
 
 
-#: the configuration keys a model is built from
-MODEL_KEYS = ("architectures", "num_hidden_layers", "hidden_size",
-              "intermediate_size", "num_attention_heads",
-              "num_key_value_heads", "head_dim", "vocab_size", "rope_theta",
-              "rms_norm_eps", "attention_bias", "qk_norm",
-              "tie_word_embeddings", "torch_dtype")
-
-
-def _frozen(d: dict):
-    """The model's keys as a hashable tuple."""
-    return tuple((k, tuple(d[k]) if isinstance(d[k], list) else d[k])
-                 for k in MODEL_KEYS)
-
-
-def model_params(m: dict, seed: int):
-    """(ModelConfig, params) of one model from ``seed``."""
-    cfg, make = _model_maker(_frozen(m))
+def model_params(fam, m: dict, seed: int):
+    """(ModelConfig, params) of one model of family ``fam`` (a module under
+    ``bench/models/``) from ``seed``."""
+    cfg, make = _model_maker(fam, json.dumps(m, sort_keys=True))
     key = jax.random.fold_in(jax.random.PRNGKey(0), seed & 0x7FFFFFFF)
     key = jax.random.fold_in(key, seed >> 31)
     return cfg, make(key)
