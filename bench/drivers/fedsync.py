@@ -5,13 +5,19 @@ the seed), a ``RoutedServer`` over the configuration's pool whose engine
 never serves (no model weights are made), and the harvest rings, filled
 with seeded evaluations. Then the first ``check_syncs`` syncs run through
 the window's own call and feed (a fresh batch of evaluations appended to
-every client, then ``FedLoop.sync()``), and the window repeats the same
-until its seconds are up. ``sync_ms`` is the window over the syncs in it.
+every client, or to ``fresh_clients`` of them drawn from the seed, then
+``FedLoop.sync()``), and the window repeats the same until its seconds
+are up. ``sync_ms`` is the window over the syncs in it.
+
+The mix's optional ``cohort`` is the loop's: each FedAvg round trains
+that many clients, drawn in the fit. On more than one chip the loop fits
+on ``client_mesh(chips)``: each chip holds a block of the clients, and
+the program pads the stack with empty clients to a multiple of the chips.
 
 Correctness: the plain reference (``bench.fedref``) replays the first
-syncs from the same router state, evaluations and keys; the compared
-numbers are each round's loss and, after the last of them, the norm of
-every parameter leaf's change.
+syncs from the same router state, evaluations, keys and cohort draws; the
+compared numbers are each round's loss and, after the last of them, the
+norm of every parameter leaf's change.
 """
 from __future__ import annotations
 
@@ -48,15 +54,18 @@ class Rings:
                "cost": np.zeros((len(self.rows), capacity), np.float32),
                "w": np.zeros((len(self.rows), capacity), np.float32)}
         for c, ring in enumerate(self.rows):
-            for i, (x, m, a, co) in enumerate(ring):
-                out["x"][c, i], out["m"][c, i] = x, m
-                out["acc"][c, i], out["cost"][c, i] = a, co
-                out["w"][c, i] = 1.0
+            if not ring:
+                continue
+            k = len(ring)
+            for key, col in zip(("x", "m", "acc", "cost"), zip(*ring)):
+                out[key][c, :k] = np.stack(col)
+            out["w"][c, :k] = 1.0
         return out
 
 
 def run(ctx) -> dict:
     import jax
+    import repro.sharding as shd
     from repro import routers
     from repro.config import FedConfig, RouterConfig
     from repro.fed.harvest import HarvestStore
@@ -70,6 +79,24 @@ def run(ctx) -> dict:
                for i, lane in enumerate(cfg["pool"])]
     n, cap = mix["clients"], mix["capacity"]
     fcfg = FedConfig(num_clients=n)
+    loop_kw = {}
+    if "cohort" in mix:
+        loop_kw["cohort"] = int(mix["cohort"])
+    if ctx.chips > 1:
+        loop_kw["mesh"] = shd.client_mesh(ctx.chips)
+    # the stack as the program fits it: padded with empty clients (no
+    # rows, no weight) to a multiple of the chips
+    n_stack = -(-n // ctx.chips) * ctx.chips
+    fresh_n = mix.get("fresh_clients")
+    pick = np.random.default_rng([seed & 0xFFFFFFFF, seed >> 32, 17])
+
+    def fresh_ids():
+        """The clients that append before a sync: all of them, or
+        ``fresh_clients`` drawn from the seed."""
+        if fresh_n is None:
+            return range(n)
+        return np.sort(pick.choice(n, size=int(fresh_n), replace=False))
+
     with ctx.phase("router and harvest"):
         rcfg = RouterConfig(d_emb=r["d_emb"], hidden=tuple(r["hidden"]),
                             num_models=len(costs))
@@ -86,14 +113,15 @@ def run(ctx) -> dict:
                            harvest=HarvestStore(r["d_emb"], capacity=cap,
                                                 clients=range(n)))
         evals = traffic.Evaluations(mix, seed, costs, r["d_emb"])
-        rings = Rings(n, cap)
+        rings = Rings(n_stack, cap)
         key0 = jax.random.fold_in(jax.random.PRNGKey(3), seed & 0x7FFFFFFF)
         key0 = jax.random.fold_in(key0, seed >> 31)
         loop = FedLoop(srv, fcfg, key=key0,
-                       cfg=FedLoopConfig(sync_every=10 ** 9, min_samples=1))
+                       cfg=FedLoopConfig(sync_every=10 ** 9, min_samples=1,
+                                         **loop_kw))
 
-        def feed(k):
-            for c in range(n):
+        def feed(k, clients=range(n)):
+            for c in clients:
                 b = evals.rows(c, k)
                 rings.add(c, b)
                 for i in range(k):
@@ -107,7 +135,7 @@ def run(ctx) -> dict:
     stacks, losses = [], []
     with ctx.phase("first syncs"):
         for _ in range(mix["check_syncs"]):
-            feed(fresh)
+            feed(fresh, fresh_ids())
             stacks.append(rings.stack(cap, r["d_emb"]))
             losses.append(list(loop.sync()["loss"]))
         after = _host(srv.router.state)
@@ -116,7 +144,7 @@ def run(ctx) -> dict:
         t0 = time.perf_counter()
         while time.perf_counter() - t0 < ctx.seconds:
             with ctx.span("bench.harvest"):
-                feed(fresh)
+                feed(fresh, fresh_ids())
             with ctx.span("bench.sync"):
                 loop.sync()
             syncs += 1
@@ -128,7 +156,8 @@ def run(ctx) -> dict:
     del srv, loop
     t1 = time.perf_counter()
     numbers, control, rejected = check(ctx, before, after, stacks, losses,
-                                       key0, fcfg, rcfg)
+                                       key0, fcfg, rcfg,
+                                       loop_kw.get("cohort"))
     ctx.log(f"comparison with the reference: {time.perf_counter() - t1:.3f}"
             " s")
     return {"attempted": syncs + len(stacks), "failed": rejected,
@@ -155,7 +184,8 @@ def _fc(fcfg) -> tuple:
                              clip_norm=fcfg.clip_norm).items()))
 
 
-def replay(state, stacks, keys, fcfg, rcfg, rounds, dtype="float32"):
+def replay(state, stacks, keys, fcfg, rcfg, rounds, dtype="float32",
+           cohort=None, exchange_left_out=0):
     """The reference's syncs: (state after the last, per-round losses)."""
     fc = _fc(fcfg)
     losses = []
@@ -164,7 +194,8 @@ def replay(state, stacks, keys, fcfg, rcfg, rounds, dtype="float32"):
             fcfg.local_epochs
         state, l = fedref.sync(state, data, key, fc_items=fc, rounds=rounds,
                                steps=steps, dropout=rcfg.dropout,
-                               dtype=dtype)
+                               dtype=dtype, cohort=cohort,
+                               exchange_left_out=exchange_left_out)
         losses.extend(np.asarray(l, np.float64).tolist())
     return _host(state), losses
 
@@ -187,13 +218,14 @@ def gaps(before, after, ref_after, losses, ref_losses, grad_norms):
     return lg, pg, len(g) - len(keep)
 
 
-def check(ctx, before, after, stacks, losses, key0, fcfg, rcfg):
+def check(ctx, before, after, stacks, losses, key0, fcfg, rcfg, cohort=None):
     """The compared numbers (with their limits), and the control's."""
     import jax
     flat = [x for per in losses for x in per]
     rounds = len(losses[0])
     keys = _keys(key0, len(stacks))
-    ref_after, ref_losses = replay(before, stacks, keys, fcfg, rcfg, rounds)
+    ref_after, ref_losses = replay(before, stacks, keys, fcfg, rcfg, rounds,
+                                   cohort=cohort)
     gn = fedref.first_grad(before, stacks[0], jax.random.PRNGKey(0),
                            fc_items=_fc(fcfg), dropout=rcfg.dropout)
     lg, pg, left_out = gaps(before, after, ref_after, flat, ref_losses, gn)
@@ -211,7 +243,7 @@ def check(ctx, before, after, stacks, losses, key0, fcfg, rcfg):
     control = {}
     if ctx.control:
         c_after, c_losses = replay(before, stacks, keys, fcfg, rcfg, rounds,
-                                   dtype="bfloat16")
+                                   dtype="bfloat16", cohort=cohort)
         clg, cpg, _ = gaps(before, c_after, ref_after, c_losses, ref_losses,
                            gn)
         # a fault planted in the reference put in the program's place: half
@@ -219,12 +251,26 @@ def check(ctx, before, after, stacks, losses, key0, fcfg, rcfg):
         half = [dict(d, w=np.where(np.arange(d["w"].shape[1]) <
                                    d["w"].shape[1] // 2, d["w"], 0.0))
                 for d in stacks]
-        h_after, h_losses = replay(before, half, keys, fcfg, rcfg, rounds)
+        h_after, h_losses = replay(before, half, keys, fcfg, rcfg, rounds,
+                                   cohort=cohort)
         hlg, hpg, _ = gaps(before, h_after, ref_after, h_losses, ref_losses,
                            gn)
         control = {"first_loss_gap": clg[0], "param_change_gap": cpg,
                    "half_batch.first_loss_gap": hlg[0],
                    "half_batch.param_change_gap": hpg}
+        if cohort is not None and ctx.chips > 1:
+            # a fault planted in the reference: the cohort's exchange
+            # between chips left out
+            x_after, x_losses = replay(before, stacks, keys, fcfg, rcfg,
+                                       rounds, cohort=cohort,
+                                       exchange_left_out=ctx.chips)
+            xlg, xpg, _ = gaps(before, x_after, ref_after, x_losses,
+                               ref_losses, gn)
+            control.update({"no_exchange.first_loss_gap": xlg[0],
+                            "no_exchange.param_change_gap": xpg})
+            ctx.log(f"control (exchange between chips left out): loss gap "
+                    f"per round {[float(f'{g:.3g}') for g in xlg]}, change "
+                    f"gap {xpg:.6g}")
         ctx.log(f"control (bfloat16): loss gap per round "
                 f"{[float(f'{g:.3g}') for g in clg]}, change gap {cpg:.6g}; "
                 f"half of the rows left out: loss gap per round "

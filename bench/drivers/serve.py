@@ -5,7 +5,10 @@ through its public entry points (``submit``, ``step``, ``routed_model``,
 Set-up makes the weights and the router from the seed, builds the server,
 and warms every shape the mix can ask for: for each lane, each prompt
 bucket of the mix's block and each admission batch of ``warm_batches``,
-one coalesced prefill, then the decode chunk. The closed loop also ramps
+one coalesced prefill, then the decode chunk. On more than one chip the
+server runs on ``data_mesh(chips)``: decode rows split over the chips, the
+paged pool and the weights whole on each (the weights made there in
+place, so that no chip holds a second copy). The closed loop also ramps
 its backlog up during set-up, at most ``ramp_per_step`` submissions a
 step, so that no admission batch outgrows the warmed ones.
 
@@ -53,6 +56,7 @@ class Serving:
     # ------------------------------------------------------------- set-up
     def build(self):
         import jax
+        import repro.sharding as shd
         from repro import routers
         from repro.config import RouterConfig
         from repro.fed.harvest import HarvestStore
@@ -62,12 +66,15 @@ class Serving:
 
         cfg, ctx = self.cfg, self.ctx
         self.models, self.families, self.params, pool = [], [], [], []
+        mesh = shd.data_mesh(ctx.chips) if ctx.chips > 1 else None
+        on_mesh = {} if mesh is None else {"mesh": mesh}
         with ctx.phase("weights"):
             for lane in cfg["pool"]:
                 m = cfg if lane["model"] == "self" else cfg[lane["model"]]
                 fam = ctx.family(m)
                 mcfg, params = weights.model_params(
-                    fam, m, self.seed + lane["seed_offset"])
+                    fam, m, self.seed + lane["seed_offset"],
+                    None if mesh is None else shd.named(mesh))
                 self.models.append(m)
                 self.families.append(fam)
                 self.params.append(params)
@@ -98,7 +105,8 @@ class Serving:
         self.srv = RoutedServer(
             pool, router, engine_cfg=self.ecfg,
             harvest=HarvestStore(r["d_emb"], capacity=1024,
-                                 clients=range(self.mix["clients"])))
+                                 clients=range(self.mix["clients"])),
+            **on_mesh)
         self.lanes = Lanes(len(pool))
         self.vocab = min(m["vocab_size"] for m in self.models)
         self.gen = traffic.stream(self.mix, self.seed, vocab=self.vocab,

@@ -4,13 +4,15 @@ program.
 
 Given the router's parameters before a sync, the stacked client
 evaluations and the sync's PRNG key, it replays the same draws as the
-deployment's protocol: per round a key split into client selection
-(``participation`` of the clients, by a permutation), per-client keys and
-an aggregation key; per local step a key split into a minibatch draw
-(``batch_size`` rows with replacement from the client's real rows) and the
-dropout masks (one per hidden layer, keep probability 1 - dropout); AdamW
-with global-norm clipping and decoupled weight decay; weights of the
-average proportional to each selected client's row count. Every product
+deployment's protocol: per round, where a ``cohort`` of C clients is
+drawn, first a key split off for it and the first C of a permutation of
+the stacked clients, whose rows alone then train; then a key split into
+client selection (``participation`` of the clients, by a permutation),
+per-client keys and an aggregation key; per local step a key split into
+a minibatch draw (``batch_size`` rows with replacement from the client's
+real rows) and the dropout masks (one per hidden layer, keep probability
+1 - dropout); AdamW with global-norm clipping and decoupled weight decay;
+weights of the average proportional to each selected client's row count. Every product
 runs in float32 at the highest precision; ``dtype=bfloat16`` is the
 control, with parameters, optimizer state and data held in bfloat16.
 
@@ -103,11 +105,32 @@ def _client(params, d, key, fc, steps, dropout, prec):
     return p, jnp.mean(losses)
 
 
+def _cohort_rows(data, idx, devices):
+    """The cohort's rows, in the order drawn. With ``devices`` (a fault,
+    for the control only): the exchange between chips left out, so that
+    each of ``devices`` chips, holding an equal block of the stacked
+    clients, trains its share of the cohort on the rows it holds itself,
+    and on zeros (no rows, no weight) where another chip holds them."""
+    rows = jax.tree.map(lambda a: jnp.take(a, idx, axis=0), data)
+    if not devices:
+        return rows
+    N, C = data["x"].shape[0], idx.shape[0]
+    own = idx // (N // devices) == jnp.arange(C) // (C // devices)
+    return jax.tree.map(lambda a: jnp.where(
+        own.reshape((C,) + (1,) * (a.ndim - 1)), a, jnp.zeros((), a.dtype)),
+        rows)
+
+
 @functools.partial(jax.jit, static_argnames=("fc_items", "rounds", "steps",
-                                             "dropout", "dtype"))
-def sync(params, data, key, *, fc_items, rounds, steps, dropout, dtype):
+                                             "dropout", "dtype", "cohort",
+                                             "exchange_left_out"))
+def sync(params, data, key, *, fc_items, rounds, steps, dropout, dtype,
+         cohort=None, exchange_left_out=0):
     """One refit: ``rounds`` FedAvg rounds from ``params`` over the stacked
-    client data. Returns (new params, per-round losses)."""
+    client data, each over a ``cohort`` of them where one is given and is
+    smaller than the stack. Returns (new params, per-round losses).
+    ``exchange_left_out`` is a fault, for the control only (see
+    ``_cohort_rows``)."""
     fc = dict(fc_items)
     low = dtype == "bfloat16"
     prec = None if low else HI
@@ -115,20 +138,28 @@ def sync(params, data, key, *, fc_items, rounds, steps, dropout, dtype):
         lambda a: a.astype(jnp.bfloat16) if a.dtype == jnp.float32 else a,
         t)) if low else (lambda t: t)
     params, data = cast(params), cast(data)
-    N = data["x"].shape[0]
+    N_all = data["x"].shape[0]
+    if cohort is not None and cohort >= N_all:
+        cohort = None                          # every client, every round
+    N = N_all if cohort is None else cohort
     n_sel = max(1, int(round(fc["participation"] * N)))
     key, _ = jax.random.split(key)             # the fit's own init key
 
     def one_round(carry, _):
         p, key = carry
         key, k_r = jax.random.split(key)
+        rows = data
+        if cohort is not None:
+            k_r, k_coh = jax.random.split(k_r)
+            idx = jax.random.permutation(k_coh, N_all)[:cohort]
+            rows = _cohort_rows(data, idx, exchange_left_out)
         _, k_sel, k_cli, _ = jax.random.split(k_r, 4)
         perm = jax.random.permutation(k_sel, N)
         active = jnp.zeros((N,)).at[perm[:n_sel]].set(1.0)
         cp, cl = jax.vmap(lambda d, k: _client(p, d, k, fc, steps, dropout,
                                                prec))(
-            data, jax.random.split(k_cli, N))
-        wts = jnp.sum(data["w"].astype(jnp.float32), -1) * active
+            rows, jax.random.split(k_cli, N))
+        wts = jnp.sum(rows["w"].astype(jnp.float32), -1) * active
         wn = wts / jnp.maximum(jnp.sum(wts), 1e-12)
         newp = jax.tree.map(lambda s, o: jnp.tensordot(
             wn.astype(s.dtype), s, axes=1, precision=prec).astype(o.dtype),

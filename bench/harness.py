@@ -148,9 +148,12 @@ class Context:
     """What a driver sees of the harness."""
 
     def __init__(self, *, config, traffic, seed, seconds, trace, control,
-                 t_start, log=None, root=ROOT):
+                 t_start, log=None, root=ROOT, chips=1):
         self.config, self.traffic = config, traffic
         self.root = root
+        #: the chips the cell asks for; a driver runs on more than one
+        #: through the program's own mesh
+        self.chips = int(chips)
         self.seed, self.seconds = seed, float(seconds)
         self.trace, self.control = bool(trace), bool(control)
         self.t_start = t_start
@@ -214,7 +217,9 @@ class Context:
                 w.end()
             self._where = "after"
             if self.trace:
+                t0 = time.perf_counter()
                 jax.profiler.stop_trace()
+                self.log(f"trace written in {time.perf_counter() - t0:.3f} s")
             self.log(f"window: {w.seconds:.4f} s; programs compiled or "
                      f"loaded inside it: {self.compiles['window']}")
 
@@ -270,7 +275,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         sys.path.insert(0, str(src))
     ctx = Context(config=r["config"], traffic=r["traffic"], seed=seed,
                   seconds=seconds, trace=trace, control=control,
-                  t_start=t_start, log=log, root=pathlib.Path(root))
+                  t_start=t_start, log=log, root=pathlib.Path(root),
+                  chips=r["cell"]["chips"])
     with ctx.phase("import and jax start"):
         try:
             importlib.import_module("repro.serve.gateway")
@@ -305,14 +311,16 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
               "failed": out["failed"], "metrics": {}, "device": dev}
     if trace:
         from bench import trace as tr
+        t0 = time.perf_counter()
         red = tr.reduce_dir(ctx.trace_dir)
+        ctx.log(f"trace reduced in {time.perf_counter() - t0:.3f} s")
         shutil.rmtree(ctx.trace_dir, ignore_errors=True)
         dev["busy_s"] = red["busy_s"]
         dev["window_s"] = red["window_s"]
         from bench import yardstick
         run = Run(facts=out["facts"], trace=red,
                   peaks=yardstick.peaks(dev["kind"]) if require_chip
-                  else None)
+                  else None, chips=ctx.chips)
         if keep is not None:
             keep["run"] = run
         for m in r["per_layer"]:
@@ -325,6 +333,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         ctx.log(f"trace: busy {red['busy_s']:.4f} s of {red['window_s']:.4f}"
                 f" s; planes {red['planes']}; device lines {red['lines']}; "
                 f"clocks {red['clock']}")
+        ctx.log("trace: idle share by device: " + ", ".join(
+            f"{d} {100.0 * (1.0 - b / red['window_s']):.3f}%"
+            for d, b in red["busy_by_device"].items()))
     else:
         for m in r["e2e"]:
             v = ctx.setup_s if m["name"] == "setup_s" else out["e2e"].get(
@@ -342,11 +353,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
 
 class Run:
     """What a per-layer reader gets: the driver's counts and host timings
-    (``facts``), the reduced trace, and the chip's peaks (None off the
-    chip)."""
+    (``facts``), the reduced trace, the chip's peaks (None off the chip)
+    and the number of chips the cell runs on."""
 
-    def __init__(self, *, facts, trace, peaks):
+    def __init__(self, *, facts, trace, peaks, chips=1):
         self.facts, self.trace, self.peaks = facts, trace, peaks
+        self.chips = int(chips)
 
 
 def main(argv=None) -> int:

@@ -38,12 +38,12 @@ def occupancy(run):
 
 def mfu(run):
     """Model FLOPs of the window's work (prefill of admitted prompts and
-    decode of every emitted token) over the window's seconds at the chip's
-    bf16 peak, per cent."""
+    decode of every emitted token) over the window's seconds at the bf16
+    peak of every chip the cell runs on, per cent."""
     f, s = run.facts.get("model_flops", 0.0), run.facts.get("window_span_s")
     if not f or not s or run.peaks is None:
         return None
-    return 100.0 * f / (s * run.peaks["flops_bf16"])
+    return 100.0 * f / (s * run.chips * run.peaks["flops_bf16"])
 
 
 def roofline(run, name):

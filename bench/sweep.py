@@ -4,10 +4,12 @@ set-up, one window per offered rate.
   python3 -m bench.sweep --workload <name> --rates 4,6,8 --seconds 30
 
 For each rate it prints the requests due and finished by the window's
-close, the queue left at the close, and the tails of time to first token
-and time per output token (all due requests waited for). The knee is the
-highest rate whose requests are still finished at the rate they are due,
-with no backlog growing through the window.
+close, the queue left at the close, the tails of time to first token and
+time per output token (all due requests waited for), and the steps after
+which a lane kept a request queued though it had a free row: admission
+waiting for pages. The knee is the highest rate whose requests are still
+finished at the rate they are due, with no backlog growing through the
+window.
 """
 import argparse
 import json
@@ -36,7 +38,8 @@ def main(argv=None) -> int:
     sys.path.insert(0, str(harness.ROOT / "src"))
     ctx = harness.Context(config=r["config"], traffic=dict(r["traffic"]),
                           seed=args.seed, seconds=args.seconds, trace=False,
-                          control=False, t_start=t_start)
+                          control=False, t_start=t_start,
+                          chips=r["cell"]["chips"])
     ctx.count_compiles()
     dev = harness.device_info(r["cell"]["chips"])
     harness.enable_cache()
@@ -44,6 +47,17 @@ def main(argv=None) -> int:
     s.build()
     s.warm()
     ctx.log(f"device {dev}; set-up {time.perf_counter() - t_start:.1f} s")
+    page_waits = Counter()
+    step = s.step
+
+    def counted(in_window):
+        done = step(in_window)
+        page_waits["steps"] += any(
+            q and len(a) < s.ecfg.slots
+            for q, a in zip(s.lanes.queue, s.lanes.active))
+        return done
+
+    s.step = counted
     for rate in (float(x) for x in args.rates.split(",")):
         s.mix = dict(r["traffic"], rate_per_s=rate)
         s.gen = traffic.stream(s.mix, args.seed, vocab=s.vocab,
@@ -75,8 +89,12 @@ def main(argv=None) -> int:
                 (lane, serve._next_pow2(n)) for lane, n in st["admitted"]
             ).most_common(1)[0][1] for st in s.steps if st["admitted"]),
                 default=0),
+            "page_waits": page_waits.pop("steps", 0),
             "window_compiles": ctx.compiles["window"]}), flush=True)
         ctx.compiles["window"] = 0
+        while s.srv.engine.busy:        # the next rate starts from idle
+            s.step(False)
+    ctx.read_memory()
     return 0
 
 

@@ -166,8 +166,9 @@ def test_evaluations_are_seeded_and_in_range(name):
 
 
 class _Run:
-    def __init__(self, facts, tr, peaks):
+    def __init__(self, facts, tr, peaks, chips=1):
         self.facts, self.trace, self.peaks = facts, tr, peaks
+        self.chips = chips
 
 
 def test_trace_reduction_arithmetic():

@@ -126,13 +126,15 @@ def reduce_events(device_ops, host_spans, window, modules=None):
     [(name, start_ns, dur_ns, args)]; window: (start_ns, end_ns); modules:
     {device: [(name, start_ns, dur_ns)]} of the "XLA Modules" lines.
 
-    Returns busy_s and window_s (busy averaged over the devices), the ten
-    operations with the most device time, the ten longest idle gaps named
+    Returns busy_s and window_s (busy averaged over the devices), each
+    device's own busy seconds (``busy_by_device``, so that a chip that
+    idles while the others work shows), the ten operations with the most
+    device time, the ten longest idle gaps named
     by the innermost host span that covers them, every operation's summed
     time (``op_s``), and the program's spans and programs (see
     ``_programs``)."""
     w0, w1 = window
-    busy, gaps = [], []
+    busy, gaps = {}, []
     op_s = collections.Counter()
     for dev, ops in sorted(device_ops.items()):
         ivs = []
@@ -144,7 +146,7 @@ def reduce_events(device_ops, host_spans, window, modules=None):
             ivs.append((s, e))
             op_s[name] += (e - s) * 1e-9
         merged = _union(ivs)
-        busy.append(sum(e - s for s, e in merged) * 1e-9)
+        busy[dev] = sum(e - s for s, e in merged) * 1e-9
         edges = [w0] + [x for iv in merged for x in iv] + [w1]
         for a, b in zip(edges[::2], edges[1::2]):
             if b > a:
@@ -153,7 +155,8 @@ def reduce_events(device_ops, host_spans, window, modules=None):
     gaps.sort(key=lambda g: g[0] - g[1])
     n_dev = max(len(device_ops), 1)
     red = {
-        "busy_s": sum(busy) / n_dev,
+        "busy_s": sum(busy.values()) / n_dev,
+        "busy_by_device": busy,
         "window_s": (w1 - w0) * 1e-9,
         "device_ops": [[n, s / n_dev] for n, s in op_s.most_common(10)],
         "idle_gaps": [[innermost(named, (a + b) / 2), (b - a) * 1e-9]
@@ -187,8 +190,10 @@ def reduce_dir(trace_dir: str) -> dict:
             by_line = {}
             for line in plane.lines:
                 lines.add(line.name)
-                by_line[line.name] = [(e.name, e.start_ns, e.duration_ns)
-                                      for e in line.events]
+                if line.name in (OPS_LINE, MODULES_LINE):
+                    by_line[line.name] = [(e.name, e.start_ns,
+                                           e.duration_ns)
+                                          for e in line.events]
             if OPS_LINE in by_line:
                 device_ops[plane.name] = _named(
                     by_line[OPS_LINE], by_line.get(MODULES_LINE, []))

@@ -57,7 +57,7 @@ def _model_leaf(key, names, shape, dtype, m):
 
 
 @functools.lru_cache(maxsize=None)
-def _model_maker(fam, m_json):
+def _model_maker(fam, m_json, sharding=None):
     from repro.models import init_params
     m = json.loads(m_json)
     cfg = fam.model_config(m)
@@ -85,13 +85,17 @@ def _model_maker(fam, m_json):
             leaves = [tok.T if a is None else a for a in leaves]
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
-    return cfg, jax.jit(make)
+    if sharding is None:
+        return cfg, jax.jit(make)
+    return cfg, jax.jit(make, out_shardings=sharding)
 
 
-def model_params(fam, m: dict, seed: int):
+def model_params(fam, m: dict, seed: int, sharding=None):
     """(ModelConfig, params) of one model of family ``fam`` (a module under
-    ``bench/models/``) from ``seed``."""
-    cfg, make = _model_maker(fam, json.dumps(m, sort_keys=True))
+    ``bench/models/``) from ``seed``. With ``sharding`` (a mesh's
+    replicated ``NamedSharding``), every leaf is made in place on every
+    chip of the mesh, and no chip ever holds a second copy."""
+    cfg, make = _model_maker(fam, json.dumps(m, sort_keys=True), sharding)
     key = jax.random.fold_in(jax.random.PRNGKey(0), seed & 0x7FFFFFFF)
     key = jax.random.fold_in(key, seed >> 31)
     return cfg, make(key)
